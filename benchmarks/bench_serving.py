@@ -28,10 +28,11 @@ comparison is ALSO emitted **roofline-normalized** (the ``*_modeled``
 rows): device seconds modeled from each engine's recorded work counters
 via ``roofline.analysis`` — the XLA f64 engine re-streams the full edge
 list every iteration with random-access gather/scatter (sector-
-inflated, ``dense_spmv_iteration_cost``), the kernel engine streams
-only the gated windows' packed f32 lanes at element width plus the
-replicated rank block, and its cross-shard halo bytes ride the
-interconnect.  The modeled ratio is the number the ≥3x acceptance gate
+inflated, ``dense_spmv_iteration_cost``), the kernel engine pays
+``gated_spmv_iteration_cost`` per sweep (the ungated XLA gather of
+``rsc[src]`` over every packed lane, then the gated windows' f32 lanes
+at element width, and the MXU passes of every grid step), and its
+cross-shard halo bytes ride the interconnect.  The modeled ratio is the number the ≥3x acceptance gate
 and the CI regression check read.
 """
 from __future__ import annotations
@@ -47,27 +48,28 @@ from repro.serve import IngestQueue, QueryClient, RankStore, ServeEngine, \
 METHODS = ("traversal", "frontier", "frontier_prune")
 RMAT_METHODS = ("frontier", "frontier_prune")
 
-# packed lane traffic per gated edge: src id 4B + inv-degree 4B +
-# rank 4B, streamed contiguously (no sector inflation)
-KERNEL_LANE_BYTES = 12.0
-
-
-def _modeled_seconds(m, num_edges, num_vertices, engine):
+def _modeled_seconds(m, num_edges, num_vertices, engine, serve):
     """Roofline device time for one serve run from its recorded work
     counters (see module docstring; model in roofline.analysis)."""
-    from repro.roofline.analysis import (HBM_BW, LINK_BW,
-                                         dense_spmv_iteration_cost)
+    from repro.roofline.analysis import (PEAKS, TARGET_KIND,
+                                         dense_spmv_iteration_cost,
+                                         gated_spmv_iteration_cost)
     iters = m["iterations_mean"] * m["batches"]
     if engine == "xla":
         return iters * dense_spmv_iteration_cost(
             num_edges=num_edges, num_vertices=num_vertices)["total_s"]
-    # gated path: only DMA'd window entries + gated output windows hit
-    # HBM at f32 element width, plus the replicated rank-source block
-    # per sweep; halo bytes ride the interconnect (single-pod comm = 0)
-    hbm = (m["edges_processed"] * KERNEL_LANE_BYTES
-           + m["vertices_processed"] * 4.0
-           + iters * num_vertices * 4.0)
-    return hbm / HBM_BW + m["comm_bytes"] / LINK_BW
+    # every iteration (the f64 polish's included) is charged as one gated
+    # sweep over the whole pack at the run's mean active entries (live
+    # lanes / BE) and windows; halo bytes ride the interconnect
+    # (single-pod comm = 0)
+    be, vb = serve.kernel_geometry.be, serve.kernel_geometry.vb
+    per_iter = max(1.0, iters)
+    sweep = gated_spmv_iteration_cost(
+        total_entries=serve.packed.src.size // be,
+        active_entries=m["edges_processed"] / be / per_iter,
+        active_windows=m["vertices_processed"] / vb / per_iter,
+        be=be, vb=vb)["total_s"]
+    return iters * sweep + m["comm_bytes"] / PEAKS[TARGET_KIND]["link_bw"]
 
 
 def _mesh():
@@ -171,7 +173,7 @@ def run(dataset="sx-mathoverflow", events=600, flush_size=64,
             modeled[eng] = n / max(1e-12,
                                    _modeled_seconds(m, num_edges,
                                                     rmat.num_vertices,
-                                                    eng))
+                                                    eng, serve))
             extra = f";shards={shards}" if m_arg is not None else ""
             emit(f"serving/{rmat.name}/{method}/{eng}", wall / max(1, n),
                  f"events_per_s={rate[eng]:.1f};"

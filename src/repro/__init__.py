@@ -9,6 +9,4 @@ import jax
 
 jax.config.update("jax_enable_x64", True)
 
-from repro import compat  # noqa: E402,F401  (installs jax.* API shims)
-
 __version__ = "1.0.0"

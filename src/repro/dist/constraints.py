@@ -22,14 +22,22 @@ from __future__ import annotations
 import math
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
-from repro.compat import active_mesh
+
+def active_mesh():
+    """The mesh installed by ``jax.set_mesh``, else None.
+
+    This is the abstract mesh (axis names and sizes, no devices): inside
+    ``jax.jit`` it is the only one JAX exposes, and axis sizes plus a
+    ``PartitionSpec`` are all a hint needs.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _axis_sizes(mesh) -> dict:
-    return dict(zip(mesh.axis_names, mesh.devices.shape)) \
-        if hasattr(mesh, "devices") else dict(mesh.shape)
+    return dict(mesh.shape)
 
 
 def _resolve(name, mesh) -> tuple:
@@ -78,5 +86,4 @@ def constrain(x: jax.Array, *logical_axes) -> jax.Array:
             spec.append(None)
     if all(s is None for s in spec):
         return x
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(*spec)))
+    return jax.lax.with_sharding_constraint(x, P(*spec))

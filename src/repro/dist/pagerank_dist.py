@@ -48,7 +48,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import pagerank as pr
 from repro.core.pagerank import (ALPHA, FRONTIER_TOL, MAX_ITER, PRUNE_TOL,
                                  TOL)
@@ -589,12 +589,19 @@ def sharded_hybrid_pagerank(mesh, sharded, spec, graph, init_ranks,
     """
     import numpy as np
 
+    def put(x, spec):
+        # commit every loop input to the sharding its in_spec names: the
+        # jit cache keys on input shardings, and ranks arrive sharded from
+        # a mesh static solve but unsharded from the polish
+        return jax.device_put(x, NamedSharding(mesh, spec))
+
     tr = obs_trace.get_tracer()
     V = spec.num_vertices
     v_pad = spec.padded_vertices
     deg = graph.out_degree(include_self_loop=True)
     inv_pad = jnp.pad((1.0 / deg).astype(jnp.float32), (0, v_pad - V))
     r_pad = jnp.pad(init_ranks.astype(jnp.float32), (0, v_pad - V))
+    sharded = put(sharded, P("model"))
     s0 = tr.now()
     if halo is not None:
         loop = _get_halo_loop(mesh, spec, halo.ids.shape[1], alpha=alpha,
@@ -606,7 +613,8 @@ def sharded_hybrid_pagerank(mesh, sharded, spec, graph, init_ranks,
                               use_kernel=use_kernel, wire=wire)
         aff_pad = jnp.pad(init_affected, (0, v_pad - V))
         r_out, it, delta, ever, edges, verts = loop(
-            sharded, halo.ids, r_pad, inv_pad, aff_pad)
+            sharded, put(halo.ids, P()), put(r_pad, P("model")),
+            put(inv_pad, P("model")), put(aff_pad, P("model")))
         ever = ever[:V]
         if comm_info is not None:
             from repro.kernels.pagerank_spmv.shard import halo_slots
@@ -621,8 +629,9 @@ def sharded_hybrid_pagerank(mesh, sharded, spec, graph, init_ranks,
                                  max_iter=max_iter, closed_form=closed_form,
                                  prune=prune, expand=expand,
                                  use_kernel=use_kernel)
-        r_out, it, delta, ever, edges, verts = loop(sharded, graph, r_pad,
-                                                    inv_pad, init_affected)
+        r_out, it, delta, ever, edges, verts = loop(
+            sharded, put(graph, P()), put(r_pad, P()), put(inv_pad, P()),
+            put(init_affected, P()))
         if comm_info is not None:
             # replicated-rank recipe: one full-rank [v_pad] f32 psum per
             # iteration on every device — the O(V) cost the halo removes
@@ -735,7 +744,8 @@ class ShardedKernelEngine:
         self.num_shards = int(mesh.shape["model"])
         pack_kw = dict(pack_kw or {})
         pack_kw.setdefault("spill_lanes_per_window", 1)
-        self.sharded, spec = pack_shards(graph, self.num_shards, **pack_kw)
+        sharded, spec = pack_shards(graph, self.num_shards, **pack_kw)
+        self.sharded = self._on_mesh(sharded)
         # pin every static: repacks must not change any shape or static
         # field (max_entries_per_window at the trivially safe bound —
         # a repack may redistribute entries to windows that grew)
@@ -758,6 +768,12 @@ class ShardedKernelEngine:
         self.last_comm_bytes = 0
         self.loop_kw = loop_kw
         self._apply = build_sharded_apply(mesh, self.spec)
+
+    def _on_mesh(self, sharded):
+        """Place a fresh pack on the ``model`` axis, where the compiled
+        update leaves its output: a pack left on the default device would
+        give the update a second input sharding, and so a retrace."""
+        return jax.device_put(sharded, NamedSharding(self.mesh, P("model")))
 
     def apply_update(self, update):
         """Route Δ to its owning shards, apply under shard_map, extend
@@ -809,7 +825,7 @@ class ShardedKernelEngine:
                 **{**self._pack_kw, "spill_lanes_per_window": 1})
         spec = spec._replace(max_entries_per_window=self.spec.num_entries)
         assert spec == self.spec, "repack changed pinned statics"
-        self.sharded = sharded
+        self.sharded = self._on_mesh(sharded)
         if self.halo is not None:
             try:
                 self.halo = build_halo(self.sharded, self.spec,

@@ -66,11 +66,13 @@ def _edge_key(src: jax.Array, dst: jax.Array, num_vertices: int) -> jax.Array:
 
 @jax.jit
 def apply_batch(graph: EdgeListGraph, update: BatchUpdate) -> EdgeListGraph:
-    """Pure function Gᵗ⁻¹, Δᵗ → Gᵗ.  O(E_cap·log + |Δ|) with static shapes.
+    """Pure function Gᵗ⁻¹, Δᵗ → Gᵗ.  O(E_cap·log|Δ| + |Δ|·log|Δ|) with
+    static shapes: nothing sorts the edge list.
 
-    Deletions: membership test via sorted-key binary search over the *batch*
-    (small), applied to every live slot.  Insertions: claim the first |Δ⁺|
-    free slots via a cumulative-sum compaction.
+    Deletions and already-present insertions: membership test via
+    sorted-key binary search over the *batch* (small), applied to every
+    live slot.  Insertions: claim the first |Δ⁺| free slots via a
+    cumulative-sum compaction.
     """
     V = graph.num_vertices
     # ---- deletions -------------------------------------------------------
@@ -84,23 +86,25 @@ def apply_batch(graph: EdgeListGraph, update: BatchUpdate) -> EdgeListGraph:
     valid = graph.valid & ~is_deleted
 
     # ---- insertions ------------------------------------------------------
-    # Skip inserts that already exist (paper's graphs are simple digraphs).
-    live_key_after = jnp.where(valid, live_key, -2)
-    live_sorted = jnp.sort(live_key_after)
-    ins_key = _edge_key(update.ins_src, update.ins_dst, V)
-    ipos = jnp.clip(jnp.searchsorted(live_sorted, ins_key), 0,
-                    live_sorted.shape[0] - 1)
-    already = live_sorted[ipos] == ins_key
-    ins_mask = update.ins_mask & ~already
-    # de-dup within the batch itself
-    ins_sorted_key = jnp.sort(jnp.where(ins_mask, ins_key, -1))
+    # Skip inserts that already exist (paper's graphs are simple digraphs)
+    # and de-dup within the batch: sort the (small) batch, not the edge
+    # list, and look every live slot up in it.
+    ins_key = jnp.where(update.ins_mask,
+                        _edge_key(update.ins_src, update.ins_dst, V), -1)
+    order = jnp.argsort(ins_key)
+    ins_sorted_key = ins_key[order]
     first_occurrence = jnp.concatenate(
         [jnp.array([True]), ins_sorted_key[1:] != ins_sorted_key[:-1]])
+    run = jnp.cumsum(first_occurrence.astype(jnp.int32)) - 1
+    live_key_after = jnp.where(valid, live_key, -2)
+    lpos = jnp.clip(jnp.searchsorted(ins_sorted_key, live_key_after), 0,
+                    ins_sorted_key.shape[0] - 1)
+    hit = ins_sorted_key[lpos] == live_key_after
+    # a live hit lands on the first key of its run; spread it to the run
+    already = jnp.zeros_like(update.ins_mask).at[run[lpos]].max(hit)[run]
     # map back: a key is kept iff it is the first among equals
-    order = jnp.argsort(jnp.where(ins_mask, ins_key, -1))
-    keep_sorted = first_occurrence & (ins_sorted_key >= 0)
-    keep = jnp.zeros_like(ins_mask).at[order].set(keep_sorted)
-    ins_mask = ins_mask & keep
+    keep_sorted = first_occurrence & (ins_sorted_key >= 0) & ~already
+    ins_mask = jnp.zeros_like(update.ins_mask).at[order].set(keep_sorted)
 
     # free-slot compaction: i-th masked insertion -> i-th free slot
     free = ~valid

@@ -9,15 +9,18 @@ only **active dst windows**":
     belonging to one dst *window* of VB consecutive vertices
     (``pack_blocks``, host-side, done once per batch update);
   * a window is *active* iff any of its VB vertices is affected;
+  * XLA gathers the per-lane source weights ``w = rsc[src] * valid``
+    ahead of the kernel (Mosaic has no general vector gather); this
+    pass reads every lane and is not gated;
   * the grid visits a **compacted list of active entries** delivered via
     scalar prefetch; the BlockSpec index_map reads the entry id from SMEM,
-    so inactive entries are never DMA'd from HBM at all — memory traffic is
-    O(active_edges), matching the CPU algorithm's O(affected work);
+    so the ``w``/``dst_rel`` rows of inactive entries are never DMA'd
+    from HBM — the kernel's memory traffic is O(active_edges);
   * excess grid steps (grid is static = NE) re-map to the last active entry
     — its block stays VMEM-resident, so they cost no HBM traffic; their
     contribution is zeroed via the ``i < n_active`` predicate;
   * the scatter within a window is a one-hot matmul
-    ``w[1,BE] @ onehot[BE,VB]`` — an MXU contraction, the canonical TPU
+    ``w[1,BE] · onehot[VB,BE]ᵀ`` — an MXU contraction, the canonical TPU
     scatter idiom (VB=256 keeps the lane dim a multiple of 128, BE=2048
     mirrors the paper's OpenMP chunk size);
   * per-window accumulation across an entry run uses the Pallas revisit
@@ -42,6 +45,10 @@ DEFAULT_VB = 256      # vertices per dst window (2 × 128 lanes)
 
 
 LANE_SENTINEL = np.iinfo(np.int64).max   # key of a never-live lane
+
+# block index constant for index_maps: a bare 0 becomes int64 under the
+# package-wide x64 mode, which Mosaic cannot lower
+_I0 = np.int32(0)
 
 
 @jax.tree_util.register_dataclass
@@ -210,29 +217,28 @@ def pack_blocks(src: np.ndarray, dst: np.ndarray, valid: np.ndarray,
 # ---------------------------------------------------------------------------
 
 def _kernel(sel_ref, win_ref, first_ref, nact_ref,     # scalar prefetch
-            src_ref, dstrel_ref, valid_ref, rsc_ref,   # tensor in
-            out_ref):                                   # tensor out
+            w_ref, dstrel_ref,                          # tensor in [1, BE]
+            out_ref):                                   # tensor out [1, VB]
     i = pl.program_id(0)
     active = (i < nact_ref[0]).astype(jnp.float32)
-    be, vb = src_ref.shape[1], out_ref.shape[1]
-    src = src_ref[0, :]
-    w = jnp.take(rsc_ref[:], src, axis=0).astype(jnp.float32)
-    w = w * valid_ref[0, :] * active                     # [BE]
-    dst_rel = dstrel_ref[0, :]
-    onehot = (dst_rel[:, None] ==
-              jax.lax.broadcasted_iota(jnp.int32, (be, vb), 1)
-              ).astype(jnp.float32)
+    be, vb = w_ref.shape[-1], out_ref.shape[-1]
+    w = w_ref[...] * active                              # [1, BE]
+    # transposed one-hot [VB, BE]: dst_rel stays a lane-major row, and the
+    # MXU contracts both operands on their lane dimension
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, (vb, be), 0)
+              == dstrel_ref[...]).astype(jnp.float32)
     part = jax.lax.dot_general(
-        w[None, :], onehot, (((1,), (0,)), ((), ())),
+        w, onehot, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)              # [1, VB]
 
     @pl.when(first_ref[i] == 1)
     def _write():
-        out_ref[0, :] = part[0]
+        out_ref[...] = part
 
     @pl.when(first_ref[i] == 0)
     def _accum():
-        out_ref[0, :] += part[0]
+        out_ref[...] += part
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -242,34 +248,35 @@ def frontier_spmv_padded(packed: PackedGraph, rsc: jax.Array,
     """Gated blocked SpMV on pre-padded buffers.  Returns f32[V_pad]
     contributions (V_pad = NW*VB); inactive windows are zeroed.
 
-    rsc: f32/bf16[V_pad] scaled ranks R/d — already padded, so an
-    iteration loop that keeps its rank buffer padded pays no per-call
-    pad/slice; active_window: bool[NW], precomputed by the caller.
+    rsc: f32/bf16[>= V_pad] scaled ranks R/d, indexed by ``packed.src``;
+    active_window: bool[NW], precomputed by the caller.  A shard-local
+    pack (shard.py) passes the full replicated vector, since its ``src``
+    stays global while its windows are local.
 
-    rsc may also be LONGER than NW*VB: a shard-local pack (shard.py)
-    scatters into its own window range but gathers by *global* src from
-    the full replicated vector — the whole rsc block is prefetched
-    either way, only its length differs.
+    The per-lane gather ``w = rsc[src] * valid`` runs in XLA ahead of the
+    kernel: Mosaic has no general vector gather, and the kernel then
+    needs no whole-vector VMEM block.  The kernel reads the ``w`` and
+    ``dst_rel`` rows of active entries only (DESIGN.md §8).
     """
     ne, be = packed.src.shape
     vb = packed.vb
     nw = packed.num_windows
-    v_pad = nw * vb
-    if rsc.shape[0] < v_pad:
-        rsc = jnp.pad(rsc, (0, v_pad - rsc.shape[0]))
-    v_rsc = rsc.shape[0]
+    w = jnp.take(rsc, packed.src, axis=0).astype(jnp.float32) * packed.valid
 
     # --- device-side active-entry compaction (stable order) ---------------
+    # active entries first, original order kept within each group: a
+    # prefix-sum permutation (a stable argsort compiles for tens of
+    # seconds on TPU at a million vertices)
     entry_active = active_window[packed.window]
-    # stable argsort: active entries first, original order preserved
-    order = jnp.argsort(~entry_active, stable=True)
-    sel = order.astype(jnp.int32)
-    nact = jnp.sum(entry_active.astype(jnp.int32)).astype(jnp.int32)
+    n_on = jnp.cumsum(entry_active.astype(jnp.int32))
+    nact = n_on[-1]
+    idx = jnp.arange(ne, dtype=jnp.int32)
+    pos = jnp.where(entry_active, n_on - 1, nact + idx - n_on)
+    sel = jnp.zeros((ne,), jnp.int32).at[pos].set(idx)
     win_sel = packed.window[sel]
     # windows of excess steps are pinned to the last active entry's window
     last = jnp.maximum(nact - 1, 0)
     pin = win_sel[last]
-    idx = jnp.arange(ne, dtype=jnp.int32)
     win_eff = jnp.where(idx < nact, win_sel, pin)
     sel_eff = jnp.where(idx < nact, sel, sel[last])
     first = jnp.where(
@@ -281,25 +288,26 @@ def frontier_spmv_padded(packed: PackedGraph, rsc: jax.Array,
     first = first.at[0].set(1)
     nact_arr = jnp.asarray([nact], jnp.int32)
 
+    # one entry (row) per block: rows go 3-D so a block's last two dims
+    # equal the array's, as Mosaic requires of a (1, BE) tile
+    row = pl.BlockSpec((None, 1, be),
+                       lambda i, sel, win, first, nact: (sel[i], _I0, _I0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(ne,),
-        in_specs=[
-            pl.BlockSpec((1, be), lambda i, sel, win, first, nact: (sel[i], 0)),
-            pl.BlockSpec((1, be), lambda i, sel, win, first, nact: (sel[i], 0)),
-            pl.BlockSpec((1, be), lambda i, sel, win, first, nact: (sel[i], 0)),
-            pl.BlockSpec((v_rsc,), lambda i, sel, win, first, nact: (0,)),
-        ],
+        in_specs=[row, row],
         out_specs=pl.BlockSpec(
-            (1, vb), lambda i, sel, win, first, nact: (win[i], 0)),
+            (None, 1, vb),
+            lambda i, sel, win, first, nact: (win[i], _I0, _I0)),
     )
     out = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nw, vb), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((nw, 1, vb), jnp.float32),
+        name="frontier_spmv",
         interpret=interpret,
     )(sel_eff, win_eff, first, nact_arr,
-      packed.src, packed.dst_rel, packed.valid, rsc)
+      w.reshape(ne, 1, be), packed.dst_rel.reshape(ne, 1, be))
     # inactive windows are never visited -> their blocks are undefined;
     # the contract (and the engine) wants zeros there.
     vmask = jnp.repeat(active_window, vb)

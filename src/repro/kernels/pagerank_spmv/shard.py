@@ -49,9 +49,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.graph.dynamic import BatchUpdate
 from repro.graph.structure import EdgeListGraph
+from repro.kernels.pagerank_spmv import ops
 from repro.kernels.pagerank_spmv.pagerank_spmv import (
     DEFAULT_BE, DEFAULT_VB, PackedGraph, frontier_spmv_padded, pack_blocks)
 from repro.kernels.pagerank_spmv.ref import frontier_spmv_ref_padded
@@ -402,9 +403,9 @@ def gated_contrib_shard(packed: PackedGraph, rsc_full: jax.Array,
 
     ``use_kernel=True`` runs the compiled Pallas kernel **on TPU only**.
     Off-TPU the jnp oracle is used even when the kernel is requested:
-    interpret-mode Pallas is not SPMD-safe under shard_map on the pinned
-    jax 0.4.x when the scalar-prefetch values diverge across devices
-    (which per-shard frontier gating inherently does) — revisited output
+    interpret-mode Pallas was not SPMD-safe under shard_map on jax 0.4.37
+    when the scalar-prefetch values diverge across devices (which
+    per-shard frontier gating inherently does) — revisited output
     blocks read uninitialized memory on some shards.  A six-entry
     minimal repro and the full caveat live in DESIGN.md §9; the oracle
     computes the identical gated contributions (same f32 math, XLA
@@ -412,7 +413,7 @@ def gated_contrib_shard(packed: PackedGraph, rsc_full: jax.Array,
     the same semantics.  ``frontier_spmv_shard`` itself stays correct in
     any single-device context (tests compare it against the oracle).
     """
-    if use_kernel and jax.default_backend() == "tpu":
+    if use_kernel and ops._on_tpu():
         return frontier_spmv_shard(packed, rsc_full, active_window,
                                    interpret=False)
     return frontier_spmv_ref_padded(packed.src, packed.dst_rel,

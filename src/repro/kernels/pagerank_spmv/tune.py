@@ -38,7 +38,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from repro.roofline.analysis import gated_spmv_iteration_cost
+from repro.roofline.analysis import TARGET_KIND, gated_spmv_iteration_cost
 
 __all__ = ["KernelGeometry", "TuneCache", "TuneInfo", "candidate_costs",
            "default_cache_path", "graph_signature", "tune_geometry",
@@ -107,10 +107,7 @@ def graph_signature(num_vertices: int, num_edges: int,
 
 def device_kind() -> str:
     import jax
-    try:
-        return jax.devices()[0].device_kind.replace(" ", "_")
-    except Exception:                                  # pragma: no cover
-        return jax.default_backend()
+    return jax.devices()[0].device_kind
 
 
 def default_cache_path() -> str:
@@ -169,7 +166,7 @@ class TuneCache:
 # ---------------------------------------------------------------------------
 
 def _geometry_cost(dst: np.ndarray, num_vertices: int, be: int, vb: int,
-                   spill: int, frontier_frac: float) -> float:
+                   spill: int, frontier_frac: float, kind: str) -> float:
     """Roofline iteration cost of (be, vb, spill) on THIS graph: entry
     counts come from the actual dst histogram (pack_blocks' exact sizing
     arithmetic), active work from the expected frontier fraction."""
@@ -187,14 +184,15 @@ def _geometry_cost(dst: np.ndarray, num_vertices: int, be: int, vb: int,
     active_entries = max(1.0, f * total_entries)
     return gated_spmv_iteration_cost(
         total_entries=total_entries, active_entries=active_entries,
-        active_windows=active_windows, be=be, vb=vb,
-        v_rsc=nw * vb)["total_s"]
+        active_windows=active_windows, be=be, vb=vb, kind=kind)["total_s"]
 
 
 def candidate_costs(dst: np.ndarray, num_vertices: int,
                     frontier_frac: float, expected_inserts: int,
-                    grid: Sequence = CANDIDATE_GRID) -> list:
-    """[(KernelGeometry, predicted_s)] ranked ascending by model cost."""
+                    grid: Sequence = CANDIDATE_GRID,
+                    kind: str = TARGET_KIND) -> list:
+    """[(KernelGeometry, predicted_s)] ranked ascending by model cost on
+    the chip ``kind`` names (``roofline.analysis.peaks_for``)."""
     dst = np.asarray(dst)
     out = []
     for be, vb in grid:
@@ -204,7 +202,7 @@ def candidate_costs(dst: np.ndarray, num_vertices: int,
         spill = spill_for_stream(nw, expected_inserts, be)
         geom = KernelGeometry(be=be, vb=vb, spill_lanes_per_window=spill)
         out.append((geom, _geometry_cost(dst, num_vertices, be, vb, spill,
-                                         frontier_frac)))
+                                         frontier_frac, kind)))
     out.sort(key=lambda t: t[1])
     return out
 
@@ -267,7 +265,8 @@ def tune_geometry(graph, *, frontier_frac: float = 0.05,
     t0 = time.perf_counter()
     n = graph.num_vertices
     e = int(graph.num_valid_edges())
-    key = f"{device_kind()}/{graph_signature(n, e, frontier_frac)}"
+    kind = device_kind()
+    key = f"{kind.replace(' ', '_')}/{graph_signature(n, e, frontier_frac)}"
     if cache is None:
         cache = TuneCache(cache_path)
     hit = cache.get(key)
@@ -277,7 +276,7 @@ def tune_geometry(graph, *, frontier_frac: float = 0.05,
 
     dst = np.asarray(graph.dst)[np.asarray(graph.valid)]
     ranked = candidate_costs(dst, n, frontier_frac, expected_inserts,
-                             grid=grid)
+                             grid=grid, kind=kind)
     source = "model"
     cands = [(g, p, None) for g, p in ranked]
     best = ranked[0][0]

@@ -1,10 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# (test hook: small-device override BEFORE jax initialises — see tests/)
-if os.environ.get("REPRO_DRYRUN_DEVICES"):
-    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                               + os.environ["REPRO_DRYRUN_DEVICES"])
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this produces:
@@ -20,7 +13,19 @@ benchmarks/roofline consumes.
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --mesh single --arch all
   PYTHONPATH=src python -m repro.launch.dryrun --mesh multi  --arch gemma3-12b --shape train_4k
+
+Run as a script it forces 512 host devices (``REPRO_DRYRUN_DEVICES``
+overrides the count) by appending to ``XLA_FLAGS`` before JAX starts;
+importing it, as the tests do, leaves ``XLA_FLAGS`` alone.
 """
+import os
+
+if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+        os.environ.get("XLA_FLAGS"),
+        "--xla_force_host_platform_device_count="
+        + os.environ.get("REPRO_DRYRUN_DEVICES", "512")]))
+
 import argparse
 import dataclasses
 import json
@@ -38,7 +43,7 @@ from repro.dist import sharding as SH
 from repro.dist.pagerank_dist import (build_distributed_step,
                                       distributed_in_shardings,
                                       distributed_input_specs)
-from repro.launch.mesh import data_axes, make_production_mesh
+from repro.launch.mesh import auto_mesh, data_axes, make_production_mesh
 from repro.train import inputs as I
 from repro.train import steps as S
 
@@ -302,8 +307,8 @@ def main(argv=None):
         # CI-scale override (REPRO_DRYRUN_DEVICES): shrink proportionally
         if multi_pod:
             d = ndev // 4
-            return jax.make_mesh((2, d, 2), ("pod", "data", "model"))
-        return jax.make_mesh((ndev // 2, 2), ("data", "model"))
+            return auto_mesh((2, d, 2), ("pod", "data", "model"))
+        return auto_mesh((ndev // 2, 2), ("data", "model"))
 
     for mesh_name in wanted:
         mesh = build_mesh(meshes[mesh_name])

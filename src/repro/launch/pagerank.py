@@ -24,7 +24,11 @@ from repro.ft.checkpoint import CheckpointManager
 from repro.graph.dynamic import apply_batch, make_batch_update
 from repro.graph.generators import TemporalStream
 from repro.graph.structure import from_coo
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh, make_test_mesh
+
+
+MESHES = ("none", "test", "production")
 
 
 def _resolve_mesh(name: str):
@@ -50,11 +54,12 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default="/tmp/repro_pr_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=5)
     ap.add_argument("--check-error", action="store_true")
-    ap.add_argument("--mesh", choices=["none", "test", "production"],
+    ap.add_argument("--mesh", choices=MESHES,
                     default="none",
                     help="replay the stream on a multi-device mesh via the "
                          "shard_map engine (repro.dist.pagerank_dist)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     mesh = _resolve_mesh(args.mesh)
     if mesh is not None:

@@ -23,23 +23,42 @@ import argparse
 import json
 import time
 
+import jax
 import numpy as np
 
 import repro  # noqa: F401
 from repro import obs
 from repro.core.api import ENGINES, METHODS
-from repro.data.snap import PAPER_TABLE1, load_temporal
+from repro.data.snap import PAPER_TABLE1, SCALES, load_temporal
 from repro.graph.dynamic import apply_batch, make_batch_update
-from repro.launch.pagerank import _resolve_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import auto_mesh
+from repro.launch.pagerank import MESHES as PAGERANK_MESHES
+from repro.launch.pagerank import _resolve_mesh as resolve_pagerank_mesh
 from repro.ppr import IndexConfig
 from repro.serve import IngestQueue, QueryClient, RankStore, ServeEngine, \
     ServeMetrics, preload_graph_and_feed
 
 
-def main(argv=None):
+MESHES = (*PAGERANK_MESHES, "model")
+
+
+def _resolve_mesh(name: str):
+    """``model`` puts every visible device on the ``model`` axis, the one
+    the kernel engine and the PPR index shard over; the other names are
+    ``launch.pagerank``'s."""
+    if name == "model":
+        return auto_mesh((1, len(jax.devices())), ("data", "model"))
+    return resolve_pagerank_mesh(name)
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="sx-mathoverflow",
                     choices=list(PAPER_TABLE1))
+    ap.add_argument("--scale", default="small", choices=SCALES,
+                    help="synthetic stand-in size: 'small' cuts |V| for "
+                         "CPU runs, 'paper' keeps Table 1's |V| and |E_T|")
     ap.add_argument("--method", default="frontier_prune", choices=METHODS)
     ap.add_argument("--engine", default="xla", choices=list(ENGINES),
                     help="rank-update engine: 'xla' (f64 segment_sum) or "
@@ -70,8 +89,7 @@ def main(argv=None):
                          "(DESIGN.md §14)")
     ap.add_argument("--ppr-len", type=int, default=16,
                     help="walk-index max length L (with --ppr-walks)")
-    ap.add_argument("--mesh", choices=["none", "test", "production"],
-                    default="none")
+    ap.add_argument("--mesh", choices=MESHES, default="none")
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=50,
                     help="checkpoint every K generations (with --ckpt-dir)")
@@ -102,10 +120,15 @@ def main(argv=None):
                          "GEN[:KIND[:VERTEX[:SCALE]]] with KIND rank|"
                          "event (e.g. 5:rank:0:4.0); implies --monitor")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap
 
+
+def run(args):
+    """Serve the feed ``args`` (``build_parser``) describes; returns
+    ``(engine, store, metrics)`` after the last event, or None when a
+    checkpoint cannot be resumed."""
     mesh = _resolve_mesh(args.mesh)
-    ds = load_temporal(args.dataset)
+    ds = load_temporal(args.dataset, scale=args.scale)
     graph, events = preload_graph_and_feed(ds, args.events)
     shards = (f" shards={int(mesh.shape['model'])}"
               if mesh is not None and args.engine == "kernel" else "")
@@ -130,7 +153,7 @@ def main(argv=None):
             print(f"FAIL: checkpoint last_seq={last_seq} exceeds the "
                   f"--events {args.events} feed; rerun with --events > "
                   f"{last_seq} (or a fresh --ckpt-dir)")
-            return 1
+            return None
         store.seed_generation(gen)             # gen clock survives restart
         if start_event > 0:         # replay the already-served prefix
             replay = events[:start_event]
@@ -247,6 +270,16 @@ def main(argv=None):
     print(f"final generation {snap.generation}, last_seq {snap.last_seq}, "
           f"queries served {m['queries_served']}")
     print("serve complete")
+    return engine, store, m
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    served = run(args)
+    if served is None:
+        return 1
+    m = served[2]
     if m["queries_served"] < args.min_queries:
         print(f"FAIL: served {m['queries_served']} < --min-queries "
               f"{args.min_queries}")

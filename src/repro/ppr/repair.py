@@ -111,37 +111,8 @@ def _resample_impl(csr: CSRView, key: jax.Array, steps: jax.Array,
 _resample = jax.jit(_resample_impl, static_argnames=("alpha",))
 
 
-def _resample_kernel_impl(csr: CSRView, key: jax.Array, steps: jax.Array,
-                          ids: jax.Array, t0: jax.Array, alpha: float,
-                          id_offset: jax.Array = 0,
-                          interpret: bool = False) -> jax.Array:
-    """Kernel-path twin of ``_resample_impl``: same gather/scatter frame,
-    but the hop recurrence runs in the bucketed Pallas kernel
-    (kernels/walk_repair) on per-hop uniforms precomputed here — the
-    split that keeps kernel repair bitwise equal to the jnp path."""
-    from repro.kernels.walk_repair.walk_repair import resample_rows
-
-    V, R, L = steps.shape
-    N = V * R
-    v = ids // R
-    r = jnp.minimum(ids % R, R - 1)
-    rows = steps[jnp.minimum(v, V - 1), r]               # [cap, L]
-    walk_keys = _walk_keys(key, (ids + id_offset).astype(jnp.uint32))
-    u = jax.vmap(_walk_draws, in_axes=(None, 0), out_axes=1)(
-        walk_keys, jnp.arange(1, L, dtype=jnp.int32))    # [cap, L-1, 2]
-    num_active = jnp.sum((ids < N).astype(jnp.int32))
-    new_rows = resample_rows(csr, rows, t0, u, alpha=alpha,
-                             num_active=num_active, interpret=interpret)
-    return steps.at[v, r].set(new_rows, mode="drop")
-
-
-_resample_kernel = jax.jit(_resample_kernel_impl,
-                           static_argnames=("alpha", "interpret"))
-
-
 def repair_walk_index(index: WalkIndex, graph_new: EdgeListGraph,
-                      touched: jax.Array, min_capacity: int = 64,
-                      use_kernel: bool = False, interpret: bool = False
+                      touched: jax.Array, min_capacity: int = 64
                       ) -> Tuple[WalkIndex, int]:
     """Repair ``index`` (valid for Gᵗ⁻¹) into the index for ``graph_new``.
 
@@ -150,10 +121,6 @@ def repair_walk_index(index: WalkIndex, graph_new: EdgeListGraph,
     count is exactly the number of stale walks — the resample-count
     invariant bench_ppr and the tests assert.  The input index is left
     intact (see the module docstring on why no buffer donation).
-
-    ``use_kernel`` routes the resample through the bucketed Pallas
-    kernel (kernels/walk_repair; ``interpret=True`` for CPU) — bitwise
-    identical to the jnp path, asserted in tests/test_ppr.py.
     """
     tr = obs_trace.get_tracer()
     s0 = tr.now()
@@ -169,12 +136,8 @@ def repair_walk_index(index: WalkIndex, graph_new: EdgeListGraph,
     # compiled resamplers instead of one per distinct stale count
     cap = min(N, max(min_capacity, 1 << (num_stale - 1).bit_length()))
     ids, t0_sel = _stale_ids(stale, t0, cap)
-    if use_kernel:
-        steps = _resample_kernel(csr_new, index.key, index.steps, ids,
-                                 t0_sel, index.alpha, interpret=interpret)
-    else:
-        steps = _resample(csr_new, index.key, index.steps, ids, t0_sel,
-                          index.alpha)
+    steps = _resample(csr_new, index.key, index.steps, ids, t0_sel,
+                      index.alpha)
     tr.sync(steps)
     tr.record("ppr.repair", s0, tr.now() - s0, stale=num_stale,
               capacity=cap)

@@ -37,9 +37,8 @@ counts of the sources it owns and one psum of the f64[V] estimate
 (8·V bytes) crosses the wire — vs shipping the multi-hundred-MB steps
 array (comm-volume table: DESIGN.md §14).
 
-Off-TPU, shard_map resampling always takes the jnp path — interpret-
-mode Pallas is not SPMD-safe under shard_map on jax 0.4.x (DESIGN.md
-§9); the walk-repair kernel engages under shard_map only on real TPU.
+Resampling runs the jnp hop recurrence (``repair._resample_impl``) on
+every backend: its per-hop CSR gathers have no Mosaic lowering.
 """
 from __future__ import annotations
 
@@ -53,12 +52,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.graph.structure import CSRView, EdgeListGraph
 from repro.kernels.pagerank_spmv.shard import ShardCapacityError
 from repro.obs import trace as obs_trace
-from repro.ppr.repair import (_device_csr, _resample_impl,
-                              _resample_kernel_impl, _stale_ids, stale_walks)
+from repro.ppr.repair import (_device_csr, _resample_impl, _stale_ids,
+                              stale_walks)
 from repro.ppr.walks import IndexConfig, WalkIndex, _build_steps_range
 
 # compiled-program builds per kind — tests assert a temporal stream
@@ -247,18 +246,14 @@ def _build_stale_fn(mesh: Mesh):
 
 
 def _build_repair_fn(mesh: Mesh, spec: WalkShardSpec, num_walks: int,
-                     alpha: float, cap: int, use_kernel: bool):
+                     alpha: float, cap: int):
     nl = spec.vertices_per_shard * num_walks
 
     def step(steps, stale, t0, csr, key):
         s = jax.lax.axis_index("model").astype(jnp.int32)
         ids, t0_sel = _stale_ids(stale[0], t0[0], cap)
-        if use_kernel:
-            new = _resample_kernel_impl(csr, key, steps[0], ids, t0_sel,
-                                        alpha, id_offset=s * nl)
-        else:
-            new = _resample_impl(csr, key, steps[0], ids, t0_sel, alpha,
-                                 id_offset=s * nl)
+        new = _resample_impl(csr, key, steps[0], ids, t0_sel, alpha,
+                             id_offset=s * nl)
         return new[None]
 
     return jax.jit(shard_map(
@@ -271,8 +266,7 @@ def _build_repair_fn(mesh: Mesh, spec: WalkShardSpec, num_walks: int,
 def _repair_stacked_host(steps_stacked: jax.Array, csr: CSRView,
                          key: jax.Array, stale: jax.Array, t0: jax.Array,
                          cap: int, alpha: float) -> jax.Array:
-    """Mesh-free oracle for the sharded resample (always the jnp path —
-    no vmap over the Pallas kernel)."""
+    """Mesh-free oracle for the sharded resample."""
     S, vps, R, L = steps_stacked.shape
     offs = jnp.arange(S, dtype=jnp.int32) * (vps * R)
 
@@ -289,8 +283,7 @@ def repair_walk_index_sharded(index: ShardedWalkIndex,
                               touched: jax.Array, *,
                               min_capacity: int = 64,
                               capacity: Optional[int] = None,
-                              check: bool = True,
-                              use_kernel: bool = False
+                              check: bool = True
                               ) -> Tuple[ShardedWalkIndex, int]:
     """Sharded twin of ``repair_walk_index``: every shard repairs its own
     stale walks under shard_map; the result is bitwise equal to
@@ -301,8 +294,7 @@ def repair_walk_index_sharded(index: ShardedWalkIndex,
     the shards (``check=False`` drops the overflow instead — those
     walks simply stay stale, degrading estimates, never corrupting
     them).  Without it the budget is the shard-local walk count, which
-    cannot overflow.  ``use_kernel`` engages the Pallas repair kernel;
-    under shard_map it takes effect only on real TPU (DESIGN.md §9).
+    cannot overflow.
     """
     tr = obs_trace.get_tracer()
     s0 = tr.now()
@@ -340,11 +332,10 @@ def repair_walk_index_sharded(index: ShardedWalkIndex,
     cap = min(budget,
               max(min_capacity,
                   1 << (min(max_stale, budget) - 1).bit_length()))
-    kern = use_kernel and jax.default_backend() == "tpu"
     if mesh is not None:
-        rfn = _cached(("repair", mesh, spec, R, L, cap, kern),
+        rfn = _cached(("repair", mesh, spec, R, L, cap),
                       lambda: _build_repair_fn(mesh, spec, R,
-                                               index.alpha, cap, kern))
+                                               index.alpha, cap))
         steps = rfn(index.steps, stale, t0, csr_new, index.key)
     else:
         steps = _repair_stacked_host(index.steps, csr_new, index.key,

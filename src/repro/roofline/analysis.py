@@ -24,11 +24,30 @@ import os
 from dataclasses import dataclass
 from typing import Dict, Optional
 
-# TPU v5e constants (per chip)
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # B/s
-LINK_BW = 50e9               # B/s per ICI link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.  A TPU
+# kind missing here is an error (``peaks_for``), never a default.
+PEAKS = {
+    "TPU v5 lite": dict(
+        flops=197e12,        # bf16 FLOP/s
+        hbm_bw=819e9,        # B/s
+        link_bw=50e9,        # B/s per ICI link (1,600 Gbit/s over 4 links)
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip"),
+}
+# the chip the dry-run tables and the geometry model target
+TARGET_KIND = "TPU v5 lite"
 CHIPS = dict(single=256, multi=512)
+
+
+def peaks_for(kind: str) -> dict:
+    """Peaks of the chip ``kind`` names.  A host without a TPU (``cpu``)
+    models the target chip; a TPU kind with no published row raises."""
+    if kind in PEAKS:
+        return PEAKS[kind]
+    if kind.upper().startswith("TPU"):
+        raise KeyError(f"no published peaks for device kind {kind!r}: add "
+                       "them, with their source, to roofline.analysis.PEAKS")
+    return PEAKS[TARGET_KIND]
 
 
 @dataclass
@@ -142,6 +161,10 @@ def pagerank_model_flops(spec, cell) -> float:
 # paid for every entry, active or not.
 SPMV_STEP_OVERHEAD_S = 1e-6
 
+# an f32 matmul at Precision.HIGHEST runs as six bf16 passes on the MXU
+# (the ``flops`` peaks above are bf16)
+F32_HIGHEST_PASSES = 6
+
 # random-access HBM traffic moves whole sectors regardless of element
 # width: a gather/scatter of one f64 still transfers a 32B sector.  The
 # dense XLA engine pays this on every edge (gather r/d by src, scatter-
@@ -153,7 +176,7 @@ GATHER_SECTOR_BYTES = 32
 def dense_spmv_iteration_cost(*, num_edges: int, num_vertices: int,
                               index_bytes: float = 8.0,
                               value_bytes: float = 8.0,
-                              hbm_bw: float = HBM_BW) -> dict:
+                              kind: str = TARGET_KIND) -> dict:
     """Roofline terms for ONE dense XLA segment-sum PageRank iteration
     (the f64 engine's step): per edge, a random gather of the source
     contribution (one sector), the scatter-add's read+write (two
@@ -163,34 +186,37 @@ def dense_spmv_iteration_cost(*, num_edges: int, num_vertices: int,
     sector inflation already accounts for the random-access penalty."""
     edge_bytes = num_edges * (3.0 * GATHER_SECTOR_BYTES + index_bytes)
     vertex_bytes = num_vertices * value_bytes * 6.0
-    memory_s = (edge_bytes + vertex_bytes) / hbm_bw
+    memory_s = (edge_bytes + vertex_bytes) / peaks_for(kind)["hbm_bw"]
     return dict(memory_s=memory_s, edge_bytes=edge_bytes,
                 vertex_bytes=vertex_bytes, total_s=memory_s)
 
 
 def gated_spmv_iteration_cost(*, total_entries: int, active_entries: float,
                               active_windows: float, be: int, vb: int,
-                              v_rsc: int, peak_flops: float = PEAK_FLOPS,
-                              hbm_bw: float = HBM_BW) -> dict:
+                              kind: str = TARGET_KIND) -> dict:
     """Roofline terms for ONE gated-SpMV iteration at a given geometry.
 
-    The asymmetry that makes geometry worth tuning: **memory traffic is
-    gated** (only active entries are DMA'd from HBM; the replicated rsc
-    block and the active output windows ride along), but **compute is
-    not** — the grid is static at ``total_entries`` steps and every step
-    runs the ``[1,BE]@[BE,VB]`` one-hot contraction (inactive steps with
-    a zeroed payload).  Large BE trims total entries (fewer wasted MXU
-    steps + less per-step overhead); small VB sharpens window gating
-    (fewer bytes per active frontier vertex) but multiplies the window
-    count and hence the entry count.  The tuner ranks candidate
-    geometries by ``total_s = max(compute_s, memory_s)``.
+    Two passes.  XLA first gathers ``w = rsc[src] * valid`` over every
+    lane (src, valid and the rsc sector read, w written): ungated, and
+    proportional to the packed lanes, padding included.  The kernel then
+    DMAs the ``w`` and ``dst_rel`` rows of *active* entries only, plus
+    the active output windows: **memory traffic is gated**, but
+    **compute is not** — the grid is static at ``total_entries`` steps
+    and every step runs the ``[1,BE]x[VB,BE]^T`` one-hot contraction
+    (inactive steps with a zeroed payload) in f32 at
+    ``Precision.HIGHEST``, i.e. ``F32_HIGHEST_PASSES`` bf16 MXU passes.  Large BE trims total
+    entries (fewer wasted MXU steps + less per-step overhead); small VB
+    sharpens window gating (fewer bytes per active frontier vertex) but
+    multiplies the window count and hence the entry count.  The tuner
+    ranks candidate geometries by ``total_s = max(compute_s, memory_s)``.
     """
-    lane_bytes = active_entries * be * (4 + 4 + 4)      # src, dst_rel, valid
+    gather_bytes = total_entries * be * (4 + 4 + 4 + GATHER_SECTOR_BYTES)
+    lane_bytes = active_entries * be * (4 + 4)          # w, dst_rel
     out_bytes = active_windows * vb * 4.0
-    rsc_bytes = float(v_rsc) * 4.0
-    memory_s = (lane_bytes + out_bytes + rsc_bytes) / hbm_bw
-    compute_s = total_entries * (2.0 * be * vb / peak_flops
-                                 + SPMV_STEP_OVERHEAD_S)
+    pk = peaks_for(kind)
+    memory_s = (gather_bytes + lane_bytes + out_bytes) / pk["hbm_bw"]
+    compute_s = total_entries * (2.0 * be * vb * F32_HIGHEST_PASSES
+                                 / pk["flops"] + SPMV_STEP_OVERHEAD_S)
     return dict(compute_s=compute_s, memory_s=memory_s,
                 total_s=max(compute_s, memory_s))
 
@@ -259,9 +285,10 @@ def build_table(results_dir: str = "results") -> list[RooflineRow]:
             flops = float(cost.get("flops", 0.0))
             byts = float(cost.get("bytes accessed", 0.0))
             cbytes = float(coll.get("total", 0.0))
-            comp = flops / PEAK_FLOPS
-            mem = byts / HBM_BW
-            col = cbytes / LINK_BW
+            pk = PEAKS[TARGET_KIND]
+            comp = flops / pk["flops"]
+            mem = byts / pk["hbm_bw"]
+            col = cbytes / pk["link_bw"]
             dom = max((comp, "compute"), (mem, "memory"),
                       (col, "collective"))[1]
             mf = model_flops(spec, cell) / CHIPS[mesh_name]

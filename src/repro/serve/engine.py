@@ -172,7 +172,9 @@ class ServeEngine:
         use_kernel = opts.pop("use_kernel", "auto")
         if use_kernel == "auto":
             use_kernel = jax.default_backend() == "tpu"
-        self._kernel_kw = dict(use_kernel=bool(use_kernel), **opts)
+        # what "auto" resolved to: True runs the Pallas kernel
+        self.use_kernel = bool(use_kernel)
+        self._kernel_kw = dict(use_kernel=self.use_kernel, **opts)
         self._packed = None
         self._sharded = None   # dist.ShardedKernelEngine (kernel + mesh)
         self.static_fallback_frac = static_fallback_frac
@@ -218,6 +220,14 @@ class ServeEngine:
         # cleared) by the step that publishes the chosen generation
         self._fault: Optional[dict] = None
         self.faults_injected = 0
+
+    @property
+    def packed(self):
+        """The packed structure the kernel engine maintains on device: a
+        ``PackedGraph``, the ``ShardedPacked`` of a mesh run, or None."""
+        if self._sharded is not None:
+            return self._sharded.sharded
+        return self._packed
 
     # ---- lifecycle -------------------------------------------------------
     def bootstrap(self, ranks: Optional[jax.Array] = None,

@@ -71,3 +71,26 @@ def test_serve_driver(tmp_path):
                  "--ckpt-dir", str(tmp_path), "--ckpt-every", "2"])
     assert "restored generation" in out2
     assert "serve complete" in out2
+
+
+def test_serve_run_returns_engine_store_metrics():
+    """``launch.serve.run`` (what ``main`` and chip_smoke.py call) serves
+    the whole feed in process and hands back the engine, the store and
+    the metrics; with no flush deadline and no static fallback every
+    batch is a full DF-P batch."""
+    import jax
+
+    from repro.launch import serve
+
+    args = serve.build_parser().parse_args(
+        ["--dataset", "sx-mathoverflow", "--events", "96",
+         "--flush-size", "32", "--flush-interval-ms", "inf",
+         "--static-fallback-frac", "1.0", "--query-every", "48"])
+    engine, store, metrics = serve.run(args)
+    assert metrics["batches"] == 3
+    assert metrics["static_fallbacks"] == 0
+    assert metrics["queries_served"] > 0
+    assert store.snapshot().last_seq == 95
+    assert engine.engine == "xla" and engine.packed is None
+    mesh = serve._resolve_mesh("model")
+    assert dict(mesh.shape) == {"data": 1, "model": len(jax.devices())}

@@ -117,8 +117,9 @@ def test_dryrun_cells_compile_on_small_mesh():
         import jax, repro
         from repro.configs.registry import get_arch
         from repro.launch.dryrun import run_cell
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
-        mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((4, 2), ("data", "model"))
+        mesh3 = auto_mesh((2, 2, 2), ("pod", "data", "model"))
         cells = [("qwen2.5-3b", "decode_32k", mesh),
                  ("graphsage-reddit", "minibatch_lg", mesh),
                  ("deepfm", "train_batch", mesh),

@@ -111,3 +111,28 @@ def test_kernel_engine_df_matches_f64_engine():
                               tol=1e-7, frontier_tol=1e-5)
     ref, _ = static_pagerank_ref(sv, dv, n, tol=1e-14)
     assert l1_error(resk.ranks, ref) < 5e-5   # f32 path tolerance
+
+
+@pytest.mark.parametrize("be,vb", [(128, 128), (512, 256), (2048, 256)])
+def test_spmv_kernel_bitwise_matches_ref(be, vb):
+    """Interpret-mode kernel == ref.py bit for bit on inputs whose sums
+    are exact in f32 (multiples of 2^-8 below 1): any summation order
+    gives the same bits, so gather, gating, windowing and the one-hot
+    scatter are checked exactly, not to a tolerance."""
+    from repro.kernels.pagerank_spmv.pagerank_spmv import \
+        frontier_spmv_padded
+    from repro.kernels.pagerank_spmv.ref import frontier_spmv_ref_padded
+    edges, n = rmat_edges(11, 8, seed=be)
+    packed = pack_blocks(edges[:, 0], edges[:, 1],
+                         np.ones(len(edges), bool), n, be=be, vb=vb,
+                         spill_lanes_per_window=16)
+    rng = np.random.default_rng(vb)
+    v_pad = packed.num_windows * vb
+    rsc = jnp.asarray(rng.integers(0, 256, v_pad) / 256.0, jnp.float32)
+    for frac in (1.0, 0.3, 0.0):
+        awin = jnp.asarray(rng.random(packed.num_windows) < frac)
+        out = frontier_spmv_padded(packed, rsc, awin, interpret=True)
+        ref = frontier_spmv_ref_padded(packed.src, packed.dst_rel,
+                                       packed.valid, packed.window, rsc,
+                                       awin, vb)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))

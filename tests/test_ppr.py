@@ -460,38 +460,6 @@ def test_sharded_program_cache_bounded(small):
 
 
 # ---------------------------------------------------------------------------
-# Pallas walk-repair kernel (kernels/walk_repair): bitwise vs the jnp path
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("seed", [0, 2])
-def test_kernel_repair_bitwise_matches_jnp(small, index, seed):
-    g, _, n = small
-    upd = _batch(small, seed)
-    g2 = apply_batch(g, upd)
-    touched = touched_vertices_mask(upd, n)
-    want, want_n = repair_walk_index(index, g2, touched)
-    got, got_n = repair_walk_index(index, g2, touched, use_kernel=True,
-                                   interpret=True)
-    assert got_n == want_n > 0
-    assert bool(jnp.all(got.steps == want.steps))
-
-
-def test_kernel_repair_bucket_tail(small, index):
-    """A stale count far from the 128-lane bucket multiple exercises the
-    gated-DMA tail: excess grid steps re-run the last active bucket
-    idempotently and padding lanes stay inert."""
-    g, _, n = small
-    # touch exactly one vertex -> its own R=64 walks + visitors: a
-    # count nowhere near a bucket boundary
-    touched = jnp.zeros((n,), bool).at[7].set(True)
-    want, want_n = repair_walk_index(index, g, touched)
-    got, got_n = repair_walk_index(index, g, touched, use_kernel=True,
-                                   interpret=True)
-    assert got_n == want_n > 0
-    assert bool(jnp.all(got.steps == want.steps))
-
-
-# ---------------------------------------------------------------------------
 # serve integration: mesh engine + the single-host-sync contract
 # ---------------------------------------------------------------------------
 

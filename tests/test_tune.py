@@ -47,6 +47,18 @@ def test_model_prefers_wider_blocks_on_dense_frontier():
     assert best.be * best.vb > worst.be * worst.vb
 
 
+def test_peaks_keyed_by_device_kind():
+    from repro.roofline.analysis import PEAKS, TARGET_KIND, peaks_for
+    assert peaks_for("TPU v5 lite") is PEAKS["TPU v5 lite"]
+    assert "source" in PEAKS["TPU v5 lite"]
+    assert peaks_for("cpu") is PEAKS[TARGET_KIND]     # no chip: the target
+    with pytest.raises(KeyError, match="TPU v9"):
+        peaks_for("TPU v9")                           # unknown chip: error
+    dst = np.asarray(_graph().dst)
+    with pytest.raises(KeyError):
+        candidate_costs(dst, 512, 0.05, 1024, kind="TPU v9")
+
+
 def test_spill_for_stream_bounds():
     assert spill_for_stream(100, 0, 512) == 16          # floor
     assert spill_for_stream(1, 10**9, 512) == 512       # ceil at BE
