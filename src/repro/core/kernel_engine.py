@@ -255,7 +255,7 @@ def fused_hybrid_pagerank(graph_new: EdgeListGraph, packed: PackedGraph,
             k.delta.astype(jnp.float64), k.affected_ever,
             k.edges_processed, k.vertices_processed,
             telemetry=_merged_telemetry(k, None) if telemetry else None)
-    with tr.span("polish.f64", program="xla_polish"):
+    with tr.span("polish.f64", program="xla_polish") as sp:
         p = pr._pagerank_loop(graph_new, k.ranks.astype(jnp.float64),
                               k.affected_ever, alpha=alpha, tol=tol,
                               frontier_tol=frontier_tol,
@@ -264,6 +264,8 @@ def fused_hybrid_pagerank(graph_new: EdgeListGraph, packed: PackedGraph,
                               prune=prune, expand=expand,
                               telemetry=telemetry)
         tr.sync(p.ranks)
+        if tr.enabled:
+            sp.set(sweeps=int(p.iterations))
     return new_packed, pr.PageRankResult(
         p.ranks, k.iterations + p.iterations, p.delta,
         k.affected_ever | p.affected_ever,
@@ -305,7 +307,7 @@ def hybrid_pagerank(graph: EdgeListGraph, packed: PackedGraph,
             k.delta.astype(jnp.float64), k.affected_ever,
             k.edges_processed, k.vertices_processed,
             telemetry=_merged_telemetry(k, None) if telemetry else None)
-    with tr.span("polish.f64", program="xla_polish"):
+    with tr.span("polish.f64", program="xla_polish") as sp:
         p = pr._pagerank_loop(graph, k.ranks.astype(jnp.float64),
                               k.affected_ever, alpha=alpha, tol=tol,
                               frontier_tol=frontier_tol,
@@ -314,6 +316,8 @@ def hybrid_pagerank(graph: EdgeListGraph, packed: PackedGraph,
                               prune=prune, expand=expand,
                               telemetry=telemetry)
         tr.sync(p.ranks)
+        if tr.enabled:
+            sp.set(sweeps=int(p.iterations))
     return pr.PageRankResult(
         p.ranks, k.iterations + p.iterations, p.delta,
         k.affected_ever | p.affected_ever,

@@ -532,11 +532,6 @@ def _get_halo_loop(mesh, spec, halo_h: int, *, alpha: float, tol: float,
     return fn
 
 
-# nominal per-link ICI bandwidth used ONLY to give the modeled
-# ``halo.exchange`` trace span a plausible duration — never for decisions
-_LINK_BW_BYTES_PER_S = 25e9
-
-
 def halo_comm_bytes(halo, iterations: int, *, wire: str = "packed",
                     expand: bool = True) -> int:
     """Wire bytes of one solve's halo exchanges (per device): each
@@ -583,9 +578,9 @@ def sharded_hybrid_pagerank(mesh, sharded, spec, graph, init_ranks,
     polish phase (the sharded f32 loops expose only their endpoint
     scalars — per-iteration rows would ride the wire every sweep, so the
     f32 phase is summarized in ``comm_info`` instead); the tracer gets a
-    span per mesh program and a modeled ``halo.exchange`` span from the
-    wire accounting (the exchange runs inside the compiled loop and
-    cannot be host-timed; ``args["modeled"]`` marks it).
+    span per mesh program, ``sharded_f32_loop`` carrying the solve's
+    wire bytes as ``comm_bytes`` (the exchange runs inside the compiled
+    loop, so it has no span of its own).
     """
     import numpy as np
 
@@ -602,56 +597,48 @@ def sharded_hybrid_pagerank(mesh, sharded, spec, graph, init_ranks,
     inv_pad = jnp.pad((1.0 / deg).astype(jnp.float32), (0, v_pad - V))
     r_pad = jnp.pad(init_ranks.astype(jnp.float32), (0, v_pad - V))
     sharded = put(sharded, P("model"))
-    s0 = tr.now()
-    if halo is not None:
-        loop = _get_halo_loop(mesh, spec, halo.ids.shape[1], alpha=alpha,
-                              tol=tol_f32,
-                              frontier_tol=kernel_frontier_tol,
-                              prune_tol=kernel_prune_tol,
-                              max_iter=max_iter, closed_form=closed_form,
-                              prune=prune, expand=expand,
-                              use_kernel=use_kernel, wire=wire)
-        aff_pad = jnp.pad(init_affected, (0, v_pad - V))
-        r_out, it, delta, ever, edges, verts = loop(
-            sharded, put(halo.ids, P()), put(r_pad, P("model")),
-            put(inv_pad, P("model")), put(aff_pad, P("model")))
-        ever = ever[:V]
+    with tr.span("sharded_f32_loop",
+                 exchange="halo" if halo is not None else "psum") as sp:
+        if halo is not None:
+            loop = _get_halo_loop(mesh, spec, halo.ids.shape[1],
+                                  alpha=alpha, tol=tol_f32,
+                                  frontier_tol=kernel_frontier_tol,
+                                  prune_tol=kernel_prune_tol,
+                                  max_iter=max_iter,
+                                  closed_form=closed_form, prune=prune,
+                                  expand=expand, use_kernel=use_kernel,
+                                  wire=wire)
+            aff_pad = jnp.pad(init_affected, (0, v_pad - V))
+            r_out, it, delta, ever, edges, verts = loop(
+                sharded, put(halo.ids, P()), put(r_pad, P("model")),
+                put(inv_pad, P("model")), put(aff_pad, P("model")))
+            ever = ever[:V]
+        else:
+            loop = _get_sharded_loop(mesh, spec, alpha=alpha, tol=tol_f32,
+                                     frontier_tol=kernel_frontier_tol,
+                                     prune_tol=kernel_prune_tol,
+                                     max_iter=max_iter,
+                                     closed_form=closed_form, prune=prune,
+                                     expand=expand, use_kernel=use_kernel)
+            r_out, it, delta, ever, edges, verts = loop(
+                sharded, put(graph, P()), put(r_pad, P()),
+                put(inv_pad, P()), put(init_affected, P()))
+        if comm_info is not None or tr.enabled:
+            # the replicated-rank recipe moves one full-rank [v_pad] f32
+            # psum per iteration on every device — the O(V) cost the
+            # halo removes
+            comm_bytes = (halo_comm_bytes(halo, int(it), wire=wire,
+                                          expand=expand)
+                          if halo is not None else int(it) * v_pad * 4)
         if comm_info is not None:
             from repro.kernels.pagerank_spmv.shard import halo_slots
             comm_info["f32_iterations"] = int(it)
-            comm_info["halo_slots"] = halo_slots(halo)
-            comm_info["comm_bytes"] = halo_comm_bytes(
-                halo, int(it), wire=wire, expand=expand)
-    else:
-        loop = _get_sharded_loop(mesh, spec, alpha=alpha, tol=tol_f32,
-                                 frontier_tol=kernel_frontier_tol,
-                                 prune_tol=kernel_prune_tol,
-                                 max_iter=max_iter, closed_form=closed_form,
-                                 prune=prune, expand=expand,
-                                 use_kernel=use_kernel)
-        r_out, it, delta, ever, edges, verts = loop(
-            sharded, put(graph, P()), put(r_pad, P()), put(inv_pad, P()),
-            put(init_affected, P()))
-        if comm_info is not None:
-            # replicated-rank recipe: one full-rank [v_pad] f32 psum per
-            # iteration on every device — the O(V) cost the halo removes
-            comm_info["f32_iterations"] = int(it)
-            comm_info["halo_slots"] = 0
-            comm_info["comm_bytes"] = int(it) * v_pad * 4
-    if tr.enabled:
-        tr.sync(r_out)
-        tr.record("sharded_f32_loop", s0, tr.now() - s0,
-                  exchange="halo" if halo is not None else "psum",
-                  iterations=int(it))
-        cb = (comm_info or {}).get("comm_bytes")
-        if cb is None:
-            cb = halo_comm_bytes(halo, int(it), wire=wire, expand=expand) \
-                if halo is not None else int(it) * v_pad * 4
-        # the exchange lives inside the compiled loop — model its span
-        # from the wire accounting instead of pretending to host-time it
-        tr.record("halo.exchange", s0, cb / _LINK_BW_BYTES_PER_S,
-                  comm_bytes=int(cb), modeled=True,
-                  wire=wire if halo is not None else "psum")
+            comm_info["halo_slots"] = (halo_slots(halo) if halo is not None
+                                       else 0)
+            comm_info["comm_bytes"] = comm_bytes
+        if tr.enabled:
+            tr.sync(r_out)
+            sp.set(iterations=int(it), comm_bytes=comm_bytes)
     # hop the replicated results off the mesh so the f64 polish runs as a
     # plain single-device jit (mixing committed mesh arrays into it would
     # be a device mismatch)
@@ -665,7 +652,7 @@ def sharded_hybrid_pagerank(mesh, sharded, spec, graph, init_ranks,
                                  jnp.asarray(np.asarray(delta),
                                              jnp.float64),
                                  ever, edges, verts)
-    with tr.span("polish.f64", program="xla_polish"):
+    with tr.span("polish.f64", program="xla_polish") as sp:
         p = pr._pagerank_loop(graph, k_ranks.astype(jnp.float64), ever,
                               alpha=alpha, tol=tol,
                               frontier_tol=frontier_tol,
@@ -673,6 +660,8 @@ def sharded_hybrid_pagerank(mesh, sharded, spec, graph, init_ranks,
                               closed_form=closed_form, prune=prune,
                               expand=expand, telemetry=telemetry)
         tr.sync(p.ranks)
+        if tr.enabled:
+            sp.set(sweeps=int(p.iterations))
     tel = None
     if telemetry and p.telemetry is not None:
         tel = FrontierTelemetry.from_padded(p.telemetry, p.iterations).data

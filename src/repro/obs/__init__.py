@@ -5,7 +5,12 @@ Two halves (DESIGN.md §11 performance, §12 correctness):
 **Performance** —
   * ``trace``    — a low-overhead, thread-safe span tracer with
     Chrome-trace-format export (``chrome://tracing`` / Perfetto) and the
-    ``timeit`` micro-helper, the one host-timing idiom;
+    ``timeit`` micro-helper, the one host-timing idiom.  A span takes
+    work counts at exit (``sp.set(sweeps=n)``), spans of one request
+    share an identifier in their args (``qid``, ``first_seq``/
+    ``last_seq``), and while tracing is on every span is also a
+    ``jax.profiler.TraceAnnotation``, so a profiler session shows the
+    program's spans beside the device ops;
   * ``frontier`` — the per-iteration convergence-telemetry schema the
     XLA and kernel engine loops record when asked (``telemetry=True``):
     affected count, L∞ residual, frontier growth/prune, active work
@@ -27,7 +32,7 @@ Two halves (DESIGN.md §11 performance, §12 correctness):
 
 Tracing and telemetry are **off by default and free when off**: the
 global tracer is disabled (spans are shared no-op context managers, no
-device syncs), and the loops' ``telemetry`` flag is static, so the
+profiler annotations, no device syncs), and the loops' ``telemetry`` flag is static, so the
 untraced hot path compiles to the identical device-program schedule.
 The correctness monitor is opt-in per engine and adds one fused
 invariant program per batch; the shadow solve runs off the hot path.

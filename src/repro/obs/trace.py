@@ -4,26 +4,39 @@ One ``Tracer`` holds a bounded, thread-safe ring buffer of *complete*
 spans (name, start, duration, thread, args) recorded against a
 monotonic clock.  The API is deliberately tiny:
 
-  * ``with tracer.span("fused_update_loop", seq=s): ...`` — a
-    context-manager span; nesting is per-thread (each thread's spans
-    land on its own Chrome-trace track and nest by interval
-    containment, the format's native rule);
+  * ``with tracer.span("polish.f64", program=p) as sp: ...;
+    sp.set(sweeps=n)`` — a context-manager span; ``set`` adds arguments
+    known only once the work is done (counts read after the span's
+    device sync).  A span's parent is the span that contains it on the
+    same thread: each thread's spans land on its own Chrome-trace track
+    and nest by interval containment, the format's native rule;
   * ``@traced("name")`` — decorator form of the same;
-  * ``tracer.record(name, t0, dur, **args)`` — an already-measured
-    interval (used when a span's start must precede work whose outcome
-    decides whether to record at all, e.g. an ingest poll that may
-    yield no batch);
-  * ``tracer.instant(name, **args)`` / ``tracer.counter(name, **vals)``
-    — point annotations and counter tracks;
+  * ``tracer.record(name, t0, dur, **args)`` — an interval measured by
+    the caller on the tracer's clock;
+  * ``tracer.instant(name, **args)`` — a point annotation;
   * ``tracer.sync(x)`` — ``jax.block_until_ready`` *only when tracing
     is enabled*, so device-program boundaries get honest durations
     without perturbing the untraced hot path.
 
+Where one request crosses several spans, the spans carry a shared
+identifier in their args rather than a parent pointer: ``qid`` for a
+query (``query.*`` and its ``query.device`` child), ``first_seq``/
+``last_seq`` for an ingest batch (``ingest.coalesce`` and the
+``serve.step`` that serves it).
+
+While the tracer is enabled, each live span also enters
+``jax.profiler.TraceAnnotation(name)``: a running profiler session
+(``jax.profiler.trace(dir)``, or the benchmark's) then holds the
+program's spans as host events on its own clock, beside the device
+ops, with no flag of its own.  ``record``ed intervals and instants stay
+in the ring buffer only.
+
 Disabled tracers are free: ``span`` returns a shared no-op context
-manager, nothing is allocated, nothing is locked, and — critically —
-nothing forces a device sync, so with tracing off the serving hot path
-runs byte-for-byte the PR-6 program schedule (tests assert the trace
-counters and ``device_programs_per_batch`` are unchanged).
+manager (whose ``set`` does nothing), nothing is allocated, nothing is
+locked, no profiler annotation is entered, and — critically — nothing
+forces a device sync, so with tracing off the serving hot path runs
+the same device-program schedule (tests assert the trace counters and
+``device_programs_per_batch`` are unchanged).
 
 Export is Chrome trace format (the JSON array-of-events flavour):
 ``to_chrome()`` returns ``{"traceEvents": [...]}`` with complete-event
@@ -49,6 +62,8 @@ import time
 from collections import deque
 from contextlib import contextmanager
 from typing import Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Span", "Tracer", "timeit", "get_tracer", "set_tracer",
@@ -104,26 +119,39 @@ class _NopSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args) -> None:
+        pass
+
 
 _NOP = _NopSpan()
 
 
 class _LiveSpan:
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    """An open span: a profiler annotation plus its ring-buffer record,
+    written at exit with the arguments given at entry and by ``set``."""
 
-    def __init__(self, tracer: "Tracer", name: str, args: Optional[dict]):
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_annotation")
+
+    def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
         self._name = name
         self._args = args
 
+    def set(self, **args) -> None:
+        """Add arguments to the span before it closes."""
+        self._args.update(args)
+
     def __enter__(self):
+        self._annotation = TraceAnnotation(self._name)
+        self._annotation.__enter__()
         self._t0 = self._tracer.now()
         return self
 
     def __exit__(self, *exc):
         t = self._tracer
-        t.record(self._name, self._t0, t.now() - self._t0,
-                 **(self._args or {}))
+        dur = t.now() - self._t0
+        self._annotation.__exit__(*exc)
+        t.record(self._name, self._t0, dur, **self._args)
         return False
 
 
@@ -156,7 +184,7 @@ class Tracer:
         """Context manager recording one complete span on this thread."""
         if not self.enabled:
             return _NOP
-        return _LiveSpan(self, name, args or None)
+        return _LiveSpan(self, name, args)
 
     def record(self, name: str, t0: float, dur: float, **args) -> None:
         """Record an interval measured by the caller (tracer-clock t0)."""
@@ -177,16 +205,6 @@ class Tracer:
                 self.dropped += 1
             self._buf.append(("i", name, self.now(), 0.0,
                               threading.get_ident(), args or None))
-
-    def counter(self, name: str, **values) -> None:
-        """Counter-track sample ("ph": "C"): numeric series over time."""
-        if not self.enabled:
-            return
-        with self._lock:
-            if len(self._buf) == self.capacity:
-                self.dropped += 1
-            self._buf.append(("C", name, self.now(), 0.0,
-                              threading.get_ident(), values))
 
     def sync(self, x) -> None:
         """``jax.block_until_ready(x)`` only when tracing is enabled, so

@@ -122,23 +122,23 @@ def repair_walk_index(index: WalkIndex, graph_new: EdgeListGraph,
     invariant bench_ppr and the tests assert.  The input index is left
     intact (see the module docstring on why no buffer donation).
     """
-    tr = obs_trace.get_tracer()
-    s0 = tr.now()
     V, R, L = index.steps.shape
     N = V * R
-    csr_new = _device_csr(graph_new)
-    stale, t0 = stale_walks(index.steps, touched)
-    num_stale = int(jnp.sum(stale))
-    if num_stale == 0:
-        tr.record("ppr.repair", s0, tr.now() - s0, stale=0)
-        return dataclasses.replace(index, csr=csr_new), 0
-    # pow2 capacity buckets: a stream of varying batches reuses a few
-    # compiled resamplers instead of one per distinct stale count
-    cap = min(N, max(min_capacity, 1 << (num_stale - 1).bit_length()))
-    ids, t0_sel = _stale_ids(stale, t0, cap)
-    steps = _resample(csr_new, index.key, index.steps, ids, t0_sel,
-                      index.alpha)
-    tr.sync(steps)
-    tr.record("ppr.repair", s0, tr.now() - s0, stale=num_stale,
-              capacity=cap)
+    tr = obs_trace.get_tracer()
+    # ``scanned``: walks whose positions the stale scan reads (all of them)
+    with tr.span("ppr.repair", scanned=N) as sp:
+        csr_new = _device_csr(graph_new)
+        stale, t0 = stale_walks(index.steps, touched)
+        num_stale = int(jnp.sum(stale))
+        if num_stale == 0:
+            sp.set(stale=0)
+            return dataclasses.replace(index, csr=csr_new), 0
+        # pow2 capacity buckets: a stream of varying batches reuses a few
+        # compiled resamplers instead of one per distinct stale count
+        cap = min(N, max(min_capacity, 1 << (num_stale - 1).bit_length()))
+        ids, t0_sel = _stale_ids(stale, t0, cap)
+        steps = _resample(csr_new, index.key, index.steps, ids, t0_sel,
+                          index.alpha)
+        tr.sync(steps)
+        sp.set(stale=num_stale, capacity=cap)
     return dataclasses.replace(index, steps=steps, csr=csr_new), num_stale

@@ -296,53 +296,56 @@ def repair_walk_index_sharded(index: ShardedWalkIndex,
     them).  Without it the budget is the shard-local walk count, which
     cannot overflow.
     """
-    tr = obs_trace.get_tracer()
-    s0 = tr.now()
     S, vps, R, L = index.steps.shape
-    spec = index.spec
-    csr_new = _device_csr(graph_new)
-    mesh = _usable_mesh(index)
-    if mesh is not None:
-        fn = _cached(("stale", mesh, S, vps, R, L),
-                     lambda: _build_stale_fn(mesh))
-        counts, stale, t0 = fn(index.steps, touched)
-    else:
-        counts, stale, t0 = _stale_stacked_host(index.steps, touched)
-    counts_h = np.asarray(counts)            # the one host sync per batch
-    num_stale = int(counts_h.sum())
-    max_stale = int(counts_h.max())
-    TRACE_COUNTS["repairs"] += 1
-    if num_stale == 0:
-        tr.record("ppr.repair_sharded", s0, tr.now() - s0, stale=0,
-                  shards=S)
-        return dataclasses.replace(index, csr=csr_new), 0
-    nl = vps * R
-    budget = nl if capacity is None else min(capacity, nl)
-    if max_stale > budget:
-        over = [s for s, c in enumerate(counts_h.tolist()) if c > budget]
-        if check:
-            raise ShardCapacityError(
-                f"stale-walk compaction overflow: {max_stale} stale walks "
-                f"on one shard exceed the budget {budget} on shards {over} "
-                f"(raise capacity or repair unsharded)", shards=over)
-        TRACE_COUNTS["dropped_stale"] += sum(
-            int(c) - budget for c in counts_h if int(c) > budget)
-    # shared pow2 capacity from the max per-shard count: every shard runs
-    # the same executable, streams reuse a handful of capacities
-    cap = min(budget,
-              max(min_capacity,
-                  1 << (min(max_stale, budget) - 1).bit_length()))
-    if mesh is not None:
-        rfn = _cached(("repair", mesh, spec, R, L, cap),
-                      lambda: _build_repair_fn(mesh, spec, R,
-                                               index.alpha, cap))
-        steps = rfn(index.steps, stale, t0, csr_new, index.key)
-    else:
-        steps = _repair_stacked_host(index.steps, csr_new, index.key,
-                                     stale, t0, cap, index.alpha)
-    tr.sync(steps)
-    tr.record("ppr.repair_sharded", s0, tr.now() - s0, stale=num_stale,
-              capacity=cap, shards=S)
+    tr = obs_trace.get_tracer()
+    # ``scanned``: walks whose positions the stale scan reads, padded
+    # rows included
+    with tr.span("ppr.repair_sharded", scanned=S * vps * R,
+                 shards=S) as sp:
+        spec = index.spec
+        csr_new = _device_csr(graph_new)
+        mesh = _usable_mesh(index)
+        if mesh is not None:
+            fn = _cached(("stale", mesh, S, vps, R, L),
+                         lambda: _build_stale_fn(mesh))
+            counts, stale, t0 = fn(index.steps, touched)
+        else:
+            counts, stale, t0 = _stale_stacked_host(index.steps, touched)
+        counts_h = np.asarray(counts)        # the one host sync per batch
+        num_stale = int(counts_h.sum())
+        max_stale = int(counts_h.max())
+        TRACE_COUNTS["repairs"] += 1
+        if num_stale == 0:
+            sp.set(stale=0)
+            return dataclasses.replace(index, csr=csr_new), 0
+        nl = vps * R
+        budget = nl if capacity is None else min(capacity, nl)
+        if max_stale > budget:
+            over = [s for s, c in enumerate(counts_h.tolist())
+                    if c > budget]
+            if check:
+                raise ShardCapacityError(
+                    f"stale-walk compaction overflow: {max_stale} stale "
+                    f"walks on one shard exceed the budget {budget} on "
+                    f"shards {over} (raise capacity or repair unsharded)",
+                    shards=over)
+            TRACE_COUNTS["dropped_stale"] += sum(
+                int(c) - budget for c in counts_h if int(c) > budget)
+        # shared pow2 capacity from the max per-shard count: every shard
+        # runs the same executable, streams reuse a handful of capacities
+        cap = min(budget,
+                  max(min_capacity,
+                      1 << (min(max_stale, budget) - 1).bit_length()))
+        if mesh is not None:
+            rfn = _cached(("repair", mesh, spec, R, L, cap),
+                          lambda: _build_repair_fn(mesh, spec, R,
+                                                   index.alpha, cap))
+            steps = rfn(index.steps, stale, t0, csr_new, index.key)
+        else:
+            steps = _repair_stacked_host(index.steps, csr_new, index.key,
+                                         stale, t0, cap, index.alpha)
+        tr.sync(steps)
+        sp.set(stale=num_stale, capacity=cap)
     return dataclasses.replace(index, steps=steps, csr=csr_new), num_stale
 
 

@@ -337,18 +337,25 @@ class ServeEngine:
 
     # ---- one micro-batch -------------------------------------------------
     def step(self, force: bool = False) -> bool:
-        """Apply one coalesced micro-batch if due; True if work was done."""
+        """Apply one coalesced micro-batch if due; True if work was done.
+
+        An empty poll records no span; a batch is served inside one
+        ``serve.step`` span, whose ``first_seq``/``last_seq`` name the
+        ``ingest.coalesce`` span that formed the batch."""
         if self._ranks is None:
             raise RuntimeError("bootstrap() before step()")
-        tr = obs_trace.get_tracer()
-        s0 = tr.now()
         batch = self.ingest.poll(force=force)
         if batch is None:
             return False
-        # poll may yield nothing, so the span is recorded after the fact
-        # (Chrome-trace nesting is by timestamps, not buffer order)
-        tr.record("ingest.coalesce", s0, tr.now() - s0,
-                  events=batch.num_events, coalesced=batch.num_coalesced)
+        tr = obs_trace.get_tracer()
+        with tr.span("serve.step", first_seq=batch.first_seq,
+                     last_seq=batch.last_seq) as sp:
+            sp.set(**self._serve_batch(batch, tr))
+        return True
+
+    def _serve_batch(self, batch, tr) -> dict:
+        """Apply, solve, repair and publish one batch; returns the
+        ``serve.step`` span's arguments."""
         tel = tr.enabled if self.telemetry is None else bool(self.telemetry)
         fault = None
         if self._fault is not None \
@@ -366,67 +373,72 @@ class ServeEngine:
                 upd.ins_dst))
             batch = batch._replace(update=upd)
         t0 = self._clock()
-        r0 = tr.now()
-        graph_new = apply_batch(self._graph, batch.update)
-        method = self.method
-        init_state = build_initial_state(self._graph, graph_new,
-                                         batch.update, self._ranks, method)
-        if (self._budget is not None and method in DYNAMIC_METHODS
-                and self._budget.carried_frontier is not None):
-            # a capped previous batch left an unconverged frontier: fold
-            # it into this batch's seed set (DF re-marks until Δ ≤ τ)
-            seeds = self._budget.seeds_for_batch(np.asarray(init_state[1]))
-            init_state = (init_state[0], jnp.asarray(seeds))
-            self.metrics.record_budget_carryover()
-        affected = init_state[1]
-        fallback = False
-        if method in ("traversal", "frontier", "frontier_prune"):
-            frac = float(jnp.mean(affected.astype(jnp.float64)))
-            if frac > self.static_fallback_frac:
-                method, fallback = "static", True
-                init_state = build_initial_state(
-                    self._graph, graph_new, batch.update, self._ranks,
-                    "static")
-        # budget cap applies to dynamic solves only: a capped static
-        # solve restarts cold every batch and would never converge,
-        # while a capped DF/DF-P batch soundly resumes from its carried
-        # frontier (straggler.IterationBudget)
-        cap = (self._budget.max_iter
-               if self._budget is not None and method in DYNAMIC_METHODS
-               else None)
-        # the fused path folds packed maintenance into the solve's first
-        # sweep — one device program for the whole f32 phase
-        fuse = (self._packed is not None and not fallback
-                and method in DYNAMIC_METHODS)
-        programs = 0
-        if self._sharded is not None:
-            from repro.kernels.pagerank_spmv.shard import ShardCapacityError
-            try:
-                self._sharded.apply_update(batch.update)
-                programs += 1
-            except ShardCapacityError as e:
-                # budget/spill/overlay exhaustion on some shard(s):
-                # repack every shard at the pinned shapes (defragments
-                # freed lanes back into window order, zero recompiles).
-                # Only the typed capacity error means "recoverable by
-                # repack" — anything else is a real bug and propagates.
-                self._sharded.repack(graph_new)
-                self.metrics.record_packed_rebuild(shards=e.shards)
-        elif self._packed is not None and not fuse:
-            from repro.kernels.pagerank_spmv.update import \
-                apply_batch_packed
-            try:
-                self._packed = apply_batch_packed(self._packed, batch.update)
-                programs += 1
-            except ValueError:
-                # spill/overlay exhaustion: repack at the pinned shapes,
-                # which also defragments freed lanes back into window order
-                self._packed = self._repack(graph_new)
-                self.metrics.record_packed_rebuild()
         # edge-list update + delta routing/packed maintenance (the fused
         # path defers maintenance into the solve program, traced there)
-        tr.record("route_update", r0, tr.now() - r0,
-                  programs=programs, fused=fuse)
+        with tr.span("route_update") as route:
+            graph_new = apply_batch(self._graph, batch.update)
+            method = self.method
+            init_state = build_initial_state(
+                self._graph, graph_new, batch.update, self._ranks, method)
+            if (self._budget is not None and method in DYNAMIC_METHODS
+                    and self._budget.carried_frontier is not None):
+                # a capped previous batch left an unconverged frontier:
+                # fold it into this batch's seed set (DF re-marks until
+                # Δ ≤ τ)
+                seeds = self._budget.seeds_for_batch(
+                    np.asarray(init_state[1]))
+                init_state = (init_state[0], jnp.asarray(seeds))
+                self.metrics.record_budget_carryover()
+            affected = init_state[1]
+            fallback = False
+            if method in ("traversal", "frontier", "frontier_prune"):
+                frac = float(jnp.mean(affected.astype(jnp.float64)))
+                if frac > self.static_fallback_frac:
+                    method, fallback = "static", True
+                    init_state = build_initial_state(
+                        self._graph, graph_new, batch.update, self._ranks,
+                        "static")
+            # budget cap applies to dynamic solves only: a capped static
+            # solve restarts cold every batch and would never converge,
+            # while a capped DF/DF-P batch soundly resumes from its
+            # carried frontier (straggler.IterationBudget)
+            cap = (self._budget.max_iter
+                   if self._budget is not None
+                   and method in DYNAMIC_METHODS else None)
+            # the fused path folds packed maintenance into the solve's
+            # first sweep — one device program for the whole f32 phase
+            fuse = (self._packed is not None and not fallback
+                    and method in DYNAMIC_METHODS)
+            programs = 0
+            if self._sharded is not None:
+                from repro.kernels.pagerank_spmv.shard import \
+                    ShardCapacityError
+                try:
+                    self._sharded.apply_update(batch.update)
+                    programs += 1
+                except ShardCapacityError as e:
+                    # budget/spill/overlay exhaustion on some shard(s):
+                    # repack every shard at the pinned shapes
+                    # (defragments freed lanes back into window order,
+                    # zero recompiles).  Only the typed capacity error
+                    # means "recoverable by repack" — anything else is a
+                    # real bug and propagates.
+                    self._sharded.repack(graph_new)
+                    self.metrics.record_packed_rebuild(shards=e.shards)
+            elif self._packed is not None and not fuse:
+                from repro.kernels.pagerank_spmv.update import \
+                    apply_batch_packed
+                try:
+                    self._packed = apply_batch_packed(self._packed,
+                                                      batch.update)
+                    programs += 1
+                except ValueError:
+                    # spill/overlay exhaustion: repack at the pinned
+                    # shapes, which also defragments freed lanes back
+                    # into window order
+                    self._packed = self._repack(graph_new)
+                    self.metrics.record_packed_rebuild()
+            route.set(programs=programs, fused=fuse)
         if fuse:
             from repro.core.kernel_engine import fused_hybrid_pagerank
             kw = dict(KERNEL_FLAGS[method], **self._kernel_kw, **self.pr_kw)
@@ -518,19 +530,16 @@ class ServeEngine:
             comm_bytes=comm, device_programs=programs)
         self._observe_batch(tr, batch, res, tel)
         if self.monitor is not None:
-            m0 = tr.now()
-            self.monitor.on_batch(
-                engine=self, batch=batch, graph=graph_new, result=res,
-                method=method, fallback=fallback, latency_s=latency,
-                affected=affected_count, fault=fault)
-            tr.record("monitor.observe", m0, tr.now() - m0)
+            with tr.span("monitor.observe"):
+                self.monitor.on_batch(
+                    engine=self, batch=batch, graph=graph_new, result=res,
+                    method=method, fallback=fallback, latency_s=latency,
+                    affected=affected_count, fault=fault)
             if self.faults_injected:
                 self.metrics.set_gauge("faults_injected",
                                        float(self.faults_injected))
-        tr.record("serve.step", s0, tr.now() - s0, method=method,
-                  events=batch.num_events, fallback=fallback,
-                  device_programs=programs)
-        return True
+        return dict(method=method, events=batch.num_events,
+                    fallback=fallback, device_programs=programs)
 
     def _observe_batch(self, tr, batch, res, tel: bool):
         """Per-batch telemetry capture + engine-attribute gauges."""

@@ -33,6 +33,7 @@ from typing import List, NamedTuple, Optional
 import numpy as np
 
 from repro.graph.dynamic import BatchUpdate, make_batch_update
+from repro.obs import trace as obs_trace
 
 INSERT = "insert"
 DELETE = "delete"
@@ -132,16 +133,25 @@ class IngestQueue:
             return len(self._pending)
 
     def poll(self, force: bool = False) -> Optional[CoalescedBatch]:
-        """Take one micro-batch if the flush policy triggers, else None."""
+        """Take one micro-batch if the flush policy triggers, else None.
+
+        A batch taken is traced as ``ingest.coalesce``; its
+        ``oldest_wait_s`` is how long the batch's oldest event waited in
+        the queue (poll time minus its arrival, on the queue's clock)."""
         with self._lock:
             n = len(self._pending)
             if n == 0:
                 return None
+            now = self._clock()
             due = (n >= self.flush_size or force or
-                   (self._clock() - self._pending[0].t
-                    >= self.flush_interval))
+                   now - self._pending[0].t >= self.flush_interval)
             if not due:
                 return None
             window = self._pending[: self.flush_size]
             del self._pending[: self.flush_size]
-        return coalesce_events(window, self.capacity, self.capacity)
+        with obs_trace.get_tracer().span("ingest.coalesce") as sp:
+            batch = coalesce_events(window, self.capacity, self.capacity)
+            sp.set(events=batch.num_events, coalesced=batch.num_coalesced,
+                   first_seq=batch.first_seq, last_seq=batch.last_seq,
+                   oldest_wait_s=now - batch.oldest_t)
+        return batch

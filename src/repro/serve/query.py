@@ -25,9 +25,18 @@ rank vector.
   seed set's effective sample (Σ deg·R, ``ppr.effective_walks``)
   clears ``min_effective_walks``; the exact path otherwise.  Cold/thin
   seeds get oracle answers, warm seeds get the fast path.
+
+With ``repro.obs`` tracing on, each call records one span —
+``query.point``, ``query.top_k`` or ``query.ppr`` — with its ``qid`` (a
+per-client counter), ``generation`` and ``staleness`` (and the PPR path
+taken as ``mode``), and inside it one ``query.device`` span with the
+same ``qid``: from dispatching the device work (for a point query, the
+device-to-host copy) to the host holding the answer.  The answers are
+read with ``np.asarray``, which blocks, so the spans add no sync.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 from collections import OrderedDict
 from functools import partial
@@ -38,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.extensions import personalized_pagerank
+from repro.obs import trace as obs_trace
 from repro.ppr import DEFAULT_MIN_EFFECTIVE_WALKS, effective_walks, \
     ppr_top_k
 from repro.serve.ingest import IngestQueue
@@ -67,6 +77,7 @@ class QueryClient:
         # queries run from any thread, so cache ops take the lock
         self._exact_cache: OrderedDict = OrderedDict()
         self._cache_lock = threading.Lock()
+        self._qids = itertools.count()     # next() is atomic under the GIL
 
     def _staleness(self, snap) -> int:
         if self.ingest is None:
@@ -80,12 +91,17 @@ class QueryClient:
     # ---- queries ---------------------------------------------------------
     def get_ranks(self, vertices: Sequence[int]) -> QueryResult:
         """Point lookups of the current ranks for the given vertices."""
-        snap = self.store.snapshot()
-        verts = np.asarray(vertices, np.int64).reshape(-1)
-        stale = self._staleness(snap)
-        self._record(stale)
-        return QueryResult(verts, np.asarray(snap.ranks)[verts],
-                           snap.generation, stale)
+        tr = obs_trace.get_tracer()
+        qid = next(self._qids)
+        with tr.span("query.point", qid=qid) as sp:
+            snap = self.store.snapshot()
+            verts = np.asarray(vertices, np.int64).reshape(-1)
+            stale = self._staleness(snap)
+            self._record(stale)
+            with tr.span("query.device", qid=qid):
+                ranks = np.asarray(snap.ranks)[verts]
+            sp.set(generation=snap.generation, staleness=stale)
+        return QueryResult(verts, ranks, snap.generation, stale)
 
     def _topk(self, ranks: jax.Array, k: int):
         fn = self._topk_fns.get(k)
@@ -97,10 +113,15 @@ class QueryClient:
 
     def top_k(self, k: int) -> QueryResult:
         """The k highest-ranked vertices (jit, cached per k)."""
-        snap = self.store.snapshot()
-        idx, vals = self._topk(snap.ranks, k)
-        stale = self._staleness(snap)
-        self._record(stale)
+        tr = obs_trace.get_tracer()
+        qid = next(self._qids)
+        with tr.span("query.top_k", qid=qid) as sp:
+            snap = self.store.snapshot()
+            with tr.span("query.device", qid=qid):
+                idx, vals = self._topk(snap.ranks, k)
+            stale = self._staleness(snap)
+            self._record(stale)
+            sp.set(generation=snap.generation, staleness=stale)
         return QueryResult(idx, vals, snap.generation, stale)
 
     def _exact_ppr_ranks(self, snap, seeds: Sequence[int],
@@ -135,31 +156,39 @@ class QueryClient:
         (see module docstring for the index/exact/auto routing)."""
         if mode not in ("auto", "index", "exact"):
             raise ValueError(f"unknown personalized_top_k mode {mode!r}")
-        snap = self.store.snapshot()
-        seeds = np.asarray(seeds, np.int64).reshape(-1)
-        if len(seeds) == 0 or seeds.min() < 0 or \
-                seeds.max() >= snap.graph.num_vertices:
-            raise ValueError("seeds must be non-empty and within "
-                             f"[0, {snap.graph.num_vertices})")
-        index = snap.ppr_index
-        if mode == "index" and index is None:
-            raise ValueError("mode='index' but the snapshot carries no walk "
-                             "index (start ServeEngine with ppr_index=)")
-        if mode == "index" and ppr_kw:
-            raise ValueError("solver options are exact-path only; "
-                             f"mode='index' got {sorted(ppr_kw)}")
-        # auto: solver options imply the exact solver's semantics, so
-        # their presence routes to it (only explicit mode="index" rejects)
-        use_index = index is not None and (
-            mode == "index" or
-            (mode == "auto" and not ppr_kw and
-             effective_walks(index, seeds) >= self.min_effective_walks))
-        if use_index:
-            idx, vals = ppr_top_k(index, seeds, k)
-            idx, vals = np.asarray(idx, np.int64), np.asarray(vals)
-        else:
-            ranks = self._exact_ppr_ranks(snap, seeds, **ppr_kw)
-            idx, vals = self._topk(ranks, k)
-        stale = self._staleness(snap)
-        self._record(stale)
+        tr = obs_trace.get_tracer()
+        qid = next(self._qids)
+        with tr.span("query.ppr", qid=qid) as sp:
+            snap = self.store.snapshot()
+            seeds = np.asarray(seeds, np.int64).reshape(-1)
+            if len(seeds) == 0 or seeds.min() < 0 or \
+                    seeds.max() >= snap.graph.num_vertices:
+                raise ValueError("seeds must be non-empty and within "
+                                 f"[0, {snap.graph.num_vertices})")
+            index = snap.ppr_index
+            if mode == "index" and index is None:
+                raise ValueError("mode='index' but the snapshot carries no "
+                                 "walk index (start ServeEngine with "
+                                 "ppr_index=)")
+            if mode == "index" and ppr_kw:
+                raise ValueError("solver options are exact-path only; "
+                                 f"mode='index' got {sorted(ppr_kw)}")
+            # auto: solver options imply the exact solver's semantics, so
+            # their presence routes to it (only explicit mode="index"
+            # rejects)
+            use_index = index is not None and (
+                mode == "index" or
+                (mode == "auto" and not ppr_kw and
+                 effective_walks(index, seeds) >= self.min_effective_walks))
+            with tr.span("query.device", qid=qid):
+                if use_index:
+                    idx, vals = ppr_top_k(index, seeds, k)
+                    idx, vals = np.asarray(idx, np.int64), np.asarray(vals)
+                else:
+                    ranks = self._exact_ppr_ranks(snap, seeds, **ppr_kw)
+                    idx, vals = self._topk(ranks, k)
+            stale = self._staleness(snap)
+            self._record(stale)
+            sp.set(generation=snap.generation, staleness=stale,
+                   mode="index" if use_index else "exact")
         return QueryResult(idx, vals, snap.generation, stale)
