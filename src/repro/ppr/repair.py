@@ -20,15 +20,25 @@ PRNG draws (walks.py).  Because those draws are a pure function of
 build on Gᵗ would produce — repair is *bitwise equivalent* to a full
 rebuild while touching only the stale walks (tests assert both).
 
-Cost shape: staleness detection is one fused gather-reduce over the
-index (the unavoidable O(V·R·L) read, analogous to DF's per-iteration
-frontier scan); resampling is compacted to the S stale walks, padded to
-a power-of-two capacity so a temporal stream reuses a handful of
-compiled resamplers instead of recompiling per batch.  The scatter back
-into the step array copies it — deliberately: the serve engine's
-published snapshot still references the previous index's buffers until
-the next publish, so in-place buffer donation would corrupt answers
-being served from it.
+Cost shape: staleness detection is the unavoidable O(V·R·L) read of
+the index (analogous to DF's per-iteration frontier scan), with two
+branches inside one program, chosen on the device from the touched
+count.  A batch touches few vertices (at most one per event: a flush of
+64 events touches at most 64), so the scan compacts the mask to
+``STALE_SCAN_IDS`` ids and compares every walk position with each of
+them: elementwise work only, one fused pass over the steps.  A larger
+mask (a bigger flush, a replica's anchor resync) takes a gather of the
+mask at every position.  At wiki-talk size (292M positions, a 1.17 GB
+index) on one TPU v5e the compare branch takes 24 ms, 22 of them the
+fused compare pass, and the gather branch 2.29 s.  Both read the steps
+as ``[R, L, V]``, the layout the TPU keeps them in (V in the lanes), so
+neither copies the index, and both give the same bits.
+Resampling is compacted to the S stale walks, padded to a power-of-two
+capacity so a temporal stream reuses a handful of compiled resamplers
+instead of recompiling per batch.  The scatter back into the step array
+copies it — deliberately: the serve engine's published snapshot still
+references the previous index's buffers until the next publish, so
+in-place buffer donation would corrupt answers being served from it.
 """
 from __future__ import annotations
 
@@ -38,6 +48,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.graph.structure import CSRView, EdgeListGraph
 from repro.obs import trace as obs_trace
@@ -46,13 +57,77 @@ from repro.ppr.walks import WalkIndex, _transition, _walk_draws, _walk_keys
 _device_csr = jax.jit(EdgeListGraph.to_device_csr)
 
 
+# Touched ids the compare branch of the stale scan tests each position
+# against.  A flush of 64 events touches at most 64 vertices.  At
+# wiki-talk size on one v5e the compare pass with 64 ids takes 22 ms,
+# and 64 more ids cost every batch another ~23 ms that no flush of 64
+# needs.
+STALE_SCAN_IDS = 64
+_NO_ID = -2          # pads the id list; a step is a vertex id or -1
+
+
+def _first_hop(visited: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """(any over hops, first hop that is True, else 0) of bool[..., L, V],
+    which is ``(visited.any(-2), argmax(visited, -2))`` in one pass."""
+    L = visited.shape[-2]
+    hop = jax.lax.broadcasted_iota(jnp.int32, visited.shape, visited.ndim - 2)
+    first = jnp.min(jnp.where(visited, hop, L), axis=-2)
+    stale = first < L
+    return stale, jnp.where(stale, first, 0)
+
+
+def _scan_ids(steps_rlv: jax.Array, touched: jax.Array):
+    # the k-th touched id is where the running count first reaches k+1: a
+    # binary search, under 0.3 ms on a v5e at wiki-talk size, where the
+    # scatter ``jnp.nonzero`` compacts with took 83 ms
+    ranks = jnp.cumsum(touched, dtype=jnp.int32)
+    k = jnp.arange(STALE_SCAN_IDS, dtype=jnp.int32)
+    ids = jnp.searchsorted(ranks, k + 1).astype(jnp.int32)
+    ids = jnp.where(k < ranks[-1], ids, _NO_ID)
+    visited = steps_rlv == ids[0]
+    for i in range(1, STALE_SCAN_IDS):
+        visited = visited | (steps_rlv == ids[i])
+    return _first_hop(visited)
+
+
+def _scan_gather(steps_rlv: jax.Array, touched: jax.Array):
+    V = touched.shape[0]
+
+    def per_walk_slot(_, s):                              # s: [L, V]
+        visited = touched[jnp.clip(s, 0, V - 1)] & (s >= 0)
+        return None, _first_hop(visited)
+
+    # one walk slot r at a time, so the gather's scratch is per slot: the
+    # whole-index gather as a cond branch compiles for a v5e at wiki-talk
+    # size to 13.1 GB of scratch, this program to 74 MB
+    _, out = jax.lax.scan(per_walk_slot, None, steps_rlv)
+    return out
+
+
 @jax.jit
 def stale_walks(steps: jax.Array, touched: jax.Array
                 ) -> Tuple[jax.Array, jax.Array]:
-    """(stale bool[V, R], first_stale_hop int32[V, R]) for a touched mask."""
-    V = touched.shape[0]
-    visited = touched[jnp.clip(steps, 0, V - 1)] & (steps >= 0)  # [V, R, L]
-    return visited.any(-1), jnp.argmax(visited, axis=-1).astype(jnp.int32)
+    """(stale bool[V, R], first_stale_hop int32[V, R]) for a touched mask.
+
+    Compares positions with the touched ids when there are at most
+    ``STALE_SCAN_IDS`` of them, else gathers the mask; ``lax.cond``
+    picks on the device, so one executable serves every touched count.
+    The predicate reads only ``touched``, so under a ``vmap`` over the
+    steps alone (``shard._stale_stacked_host``) the cond stays a cond
+    and runs one branch.
+    """
+    steps_rlv = steps.transpose(1, 2, 0)
+    few = jnp.sum(touched, dtype=jnp.int32) <= STALE_SCAN_IDS
+    stale, t0 = jax.lax.cond(few, _scan_ids, _scan_gather, steps_rlv,
+                             touched)
+    return stale.T, t0.T
+
+
+@jax.jit
+def _scan_counts(stale: jax.Array, touched: jax.Array) -> jax.Array:
+    """int32[2]: stale walks, touched vertices; one host read for both."""
+    return jnp.stack([jnp.sum(stale, dtype=jnp.int32),
+                      jnp.sum(touched, dtype=jnp.int32)])
 
 
 @partial(jax.jit, static_argnames=("cap",))
@@ -129,7 +204,11 @@ def repair_walk_index(index: WalkIndex, graph_new: EdgeListGraph,
     with tr.span("ppr.repair", scanned=N) as sp:
         csr_new = _device_csr(graph_new)
         stale, t0 = stale_walks(index.steps, touched)
-        num_stale = int(jnp.sum(stale))
+        num_stale, num_touched = (
+            int(c) for c in np.asarray(_scan_counts(stale, touched)))
+        # which branch of the stale scan ran
+        sp.set(touched=num_touched,
+               scan="ids" if num_touched <= STALE_SCAN_IDS else "gather")
         if num_stale == 0:
             sp.set(stale=0)
             return dataclasses.replace(index, csr=csr_new), 0

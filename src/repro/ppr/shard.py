@@ -225,7 +225,9 @@ def build_sharded_walk_index(graph: EdgeListGraph,
 
 @jax.jit
 def _stale_stacked_host(steps_stacked: jax.Array, touched: jax.Array):
-    """Mesh-free oracle: per-shard (count, stale, t0) via vmap."""
+    """Mesh-free path: per-shard (count, stale, t0) via vmap over the
+    steps.  ``touched`` is not mapped, so the stale scan's ``lax.cond``
+    keeps an unbatched predicate and runs one branch, as on a mesh."""
 
     def per(local):
         stale, t0 = stale_walks(local, touched)

@@ -7,6 +7,7 @@ import sys
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 import repro  # noqa: F401
@@ -22,6 +23,7 @@ from repro.ppr import (IndexConfig, ShardedWalkIndex, build_sharded_walk_index,
                        repair_walk_index, repair_walk_index_sharded,
                        shard_walk_index, stale_walks, truncation_bias,
                        unshard_walk_index, walks_for_error)
+from repro.ppr.repair import STALE_SCAN_IDS
 from repro.serve import (IngestQueue, QueryClient, RankStore, ServeEngine,
                          ServeMetrics)
 
@@ -232,6 +234,111 @@ def test_repair_chain_over_stream(small):
         cur = nxt
     fresh = build_walk_index(cur, cfg)
     assert bool(jnp.all(idx.steps == fresh.steps))
+
+
+# ---------------------------------------------------------------------------
+# the stale scan: compare branch, gather branch, one executable
+# ---------------------------------------------------------------------------
+
+def _gather_oracle(steps, touched):
+    """The scan as one gather of the mask at every walk position."""
+    V = touched.shape[0]
+    visited = touched[jnp.clip(steps, 0, V - 1)] & (steps >= 0)
+    return visited.any(-1), jnp.argmax(visited, axis=-1).astype(jnp.int32)
+
+
+def _random_steps(rng, V, R=4, L=8):
+    """Random walks over V vertices with -1 tails (length 1..L)."""
+    steps = rng.integers(0, V, size=(V, R, L)).astype(np.int32)
+    steps[:, :, 0] = np.arange(V)[:, None]
+    length = rng.integers(1, L + 1, size=(V, R, 1))
+    return np.where(np.arange(L) < length, steps, -1).astype(np.int32)
+
+
+_SCAN_V = 200
+
+
+def _scan_mask(case, rng):
+    V, K = _SCAN_V, STALE_SCAN_IDS
+    m = np.zeros(V, bool)
+    if case == "random":
+        m[rng.choice(V, 12, replace=False)] = True
+    elif case == "vertex_0":
+        m[0] = True
+    elif case == "vertex_last":
+        m[V - 1] = True
+    elif case in ("exactly_k", "k_plus_1"):
+        m[rng.choice(V, K + (case == "k_plus_1"), replace=False)] = True
+    elif case == "all":
+        m[:] = True
+    return m
+
+
+@pytest.mark.parametrize("case", ["random", "vertex_0", "vertex_last",
+                                  "empty", "exactly_k", "k_plus_1", "all"])
+def test_stale_scan_matches_gather_oracle(case):
+    """``stale_walks`` gives the gather's (stale, first_stale_hop) bit for
+    bit on both sides of the compare/gather switch: vertex 0 is where a
+    -1 step lands once clipped, V-1 the last id, K and K+1 touched ids
+    the two sides of the switch."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    steps = jnp.asarray(_random_steps(rng, _SCAN_V))
+    touched = jnp.asarray(_scan_mask(case, rng))
+    stale, t0 = stale_walks(steps, touched)
+    want_stale, want_t0 = _gather_oracle(steps, touched)
+    assert stale.dtype == want_stale.dtype and t0.dtype == want_t0.dtype
+    assert np.array_equal(np.asarray(stale), np.asarray(want_stale))
+    assert np.array_equal(np.asarray(t0), np.asarray(want_t0))
+    if case == "empty":
+        assert not bool(jnp.any(stale))
+    elif case == "all":     # every source is touched
+        assert bool(jnp.all(stale)) and not bool(jnp.any(t0))
+    else:   # both stale and kept walks, and stale ones past hop 0
+        assert 0 < int(jnp.sum(stale)) < stale.size
+        assert int(t0.max()) > 0
+
+
+def test_stale_scan_one_executable_for_every_touched_count():
+    """After a one-vertex warm-up (the benchmark's walk warm-up), touched
+    counts of 1, K and K+1 run the same executable: the branch is picked
+    on the device, so no batch compiles inside a serving window."""
+    rng = np.random.default_rng(9)
+    V = _SCAN_V + 3                      # a shape no other test compiles
+    steps = jnp.asarray(_random_steps(rng, V))
+    masks = []
+    for n in (1, STALE_SCAN_IDS, STALE_SCAN_IDS + 1):
+        m = np.zeros(V, bool)
+        m[rng.choice(V, n, replace=False)] = True
+        masks.append(jnp.asarray(m))
+    compiled = []
+
+    def on_event(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        jax.block_until_ready(stale_walks(steps, masks[0]))     # warm-up
+        assert any("stale_walks" in str(f) for f in compiled)
+        del compiled[:]
+        for m in masks:
+            got = stale_walks(steps, m)
+            want = _gather_oracle(steps, m)
+            assert np.array_equal(np.asarray(got[1]), np.asarray(want[1]))
+        assert not any("stale_walks" in str(f) for f in compiled)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+
+
+def test_stale_scan_cond_survives_the_shard_vmap():
+    """The mesh-free sharded scan maps over the steps alone, so the
+    cond's predicate (a count of ``touched``) is not batched and the
+    program keeps a cond that runs one branch, not a select of both."""
+    from repro.ppr.shard import _stale_stacked_host
+    steps = jnp.zeros((2, 10, 4, 8), jnp.int32)
+    touched = jnp.zeros(20, bool)
+    jaxpr = str(jax.make_jaxpr(_stale_stacked_host)(steps, touched))
+    assert "cond[" in jaxpr
 
 
 # ---------------------------------------------------------------------------

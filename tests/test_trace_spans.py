@@ -22,6 +22,7 @@ from repro.graph.structure import from_coo
 from repro.kernels.pagerank_spmv.update import pack_graph
 from repro.obs.trace import Tracer
 from repro.ppr import IndexConfig, build_walk_index, repair_walk_index
+from repro.ppr.repair import STALE_SCAN_IDS
 from repro.ppr.shard import repair_walk_index_sharded, shard_walk_index
 from repro.serve import IngestQueue, QueryClient, RankStore, ServeEngine, \
     ServeMetrics
@@ -270,6 +271,34 @@ def test_repair_span_counts_walks_scanned(shards):
     assert 0 < span.args["stale"] == stale <= span.args["scanned"]
     # the sharded capacity is per shard, sized from its most stale one
     assert span.args["capacity"] * (shards or 1) >= stale
+    if shards is None:
+        assert span.args["touched"] == int(jnp.sum(touched))
+        assert span.args["scan"] == "ids"
+
+
+@pytest.mark.parametrize("over", [False, True])
+def test_repair_span_names_the_scan_branch(over):
+    """``touched`` and ``scan`` say which branch of the stale scan ran:
+    compare with the ids up to ``STALE_SCAN_IDS`` touched vertices, the
+    gather past them; ``scanned`` is V·R either way."""
+    g = _rmat(seed=5)
+    n = g.num_vertices
+    cfg = IndexConfig(num_walks=4, max_len=8, seed=0)
+    index = build_walk_index(g, cfg)
+    count = STALE_SCAN_IDS + 1 if over else STALE_SCAN_IDS
+    rng = np.random.default_rng(6)
+    src = rng.choice(n, count, replace=False).astype(np.int32)
+    pairs = np.stack([src, (src + 1) % n], axis=1)
+    upd = make_batch_update(np.zeros((0, 2), np.int32), pairs, 8, count)
+    touched = touched_vertices_mask(upd, n)
+    assert int(jnp.sum(touched)) == count
+    with obs.tracing() as tr:
+        _, stale = repair_walk_index(index, apply_batch(g, upd), touched)
+    (span,) = tr.spans("ppr.repair")
+    assert span.args["touched"] == count
+    assert span.args["scan"] == ("gather" if over else "ids")
+    assert span.args["scanned"] == n * cfg.num_walks
+    assert span.args["stale"] == stale > 0
 
 
 def test_repair_span_with_nothing_stale():
@@ -280,7 +309,8 @@ def test_repair_span_with_nothing_stale():
         _, stale = repair_walk_index(index, g, touched)
     (span,) = tr.spans("ppr.repair")
     assert stale == 0
-    assert span.args == dict(scanned=g.num_vertices * 2, stale=0)
+    assert span.args == dict(scanned=g.num_vertices * 2, stale=0,
+                             touched=0, scan="ids")
 
 
 # ---------------------------------------------------------------------------
